@@ -22,13 +22,9 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import flax.linen as nn
 import numpy as np
-
-try:
-    import flax.linen as nn
-    from flax import serialization
-except ImportError:  # pragma: no cover - flax is baked into the image
-    nn = None
+from flax import serialization
 
 
 def _scale3(sf) -> Tuple[int, int, int]:

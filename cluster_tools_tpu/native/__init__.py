@@ -1,14 +1,17 @@
 """ctypes bindings for the native C++ solver library.
 
-Builds ``libctt_solvers.so`` from ``solvers.cpp`` with g++ on first use (no
-pybind11 in this environment; plain C ABI + ctypes instead).  ``available()``
-reports whether the native library could be built/loaded; callers fall back to
-the pure-python implementations in ``ops.multicut`` / ``ops.mws``.
+Builds ``solvers.cpp`` with g++ on first use (no pybind11 in this
+environment; plain C ABI + ctypes instead) into ``_lib/``, under a file
+name keyed by the hash of the source: a checkout never loads a library
+that was not built from its own ``solvers.cpp``.  ``available()`` reports
+whether the native library could be built/loaded; callers fall back to the
+pure-python implementations in ``ops.multicut`` / ``ops.mws``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,19 +21,30 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "solvers.cpp")
-_LIB = os.path.join(_HERE, "libctt_solvers.so")
+_LIB_DIR = os.path.join(_HERE, "_lib")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _build() -> bool:
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", _LIB, _SRC,
-    ]
+def lib_path(src: str = _SRC) -> str:
+    """Where the library built from ``src`` lives: keyed by a hash of the
+    source's content, so an edit selects a fresh build."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_LIB_DIR, f"libctt_solvers.{digest}.so")
+
+
+def _build(out: str) -> bool:
+    os.makedirs(_LIB_DIR, exist_ok=True)
+    # concurrent builders (test workers, job processes) each compile to
+    # their own temp name; the atomic rename publishes one of them
+    tmp = f"{out}.tmp{os.getpid()}"
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+        os.replace(tmp, out)
         return True
     except (subprocess.CalledProcessError, FileNotFoundError, subprocess.TimeoutExpired) as e:
         stderr = getattr(e, "stderr", b"")
@@ -44,26 +58,17 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-            if not _build():
-                _build_failed = True
-                return None
+        path = lib_path()
+        if not os.path.exists(path) and not _build(path):
+            _build_failed = True
+            return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError as e:
-            # a prebuilt .so from another toolchain (GLIBCXX/arch mismatch)
-            # must trigger a local rebuild, not crash every caller
-            print(f"[native] prebuilt library unusable ({e}); rebuilding")
-            if not _build():
-                _build_failed = True
-                return None
-            try:
-                lib = ctypes.CDLL(_LIB)
-            except OSError as e2:
-                print(f"[native] rebuilt library failed to load ({e2}); "
-                      "falling back to python solvers")
-                _build_failed = True
-                return None
+            print(f"[native] library failed to load ({e}); "
+                  "falling back to python solvers")
+            _build_failed = True
+            return None
         i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
         f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
         u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")

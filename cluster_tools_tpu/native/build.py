@@ -1,6 +1,6 @@
 """Prebuild the native solver library: ``python -m cluster_tools_tpu.native.build``."""
 
-from . import _build, available
+from . import available
 
 if __name__ == "__main__":
     ok = available()

@@ -18,9 +18,9 @@ tests/test_pallas_cc.py), so the two paths are drop-in interchangeable.
 
 Activation mirrors the flood kernel: `CTT_CC_MODE=pallas` opts
 `connectivity=1` 3d volumes with lane-aligned slices (H % 8 == 0,
-W % 128 == 0) into this path on the TPU backend; everything else falls back
-to the XLA program.  Off by default until hardware-validated
-(tools/tpu_validate.py measures it when a chip is reachable).
+W % 128 == 0, at most ``_MAX_SLICE_ELEMS`` per slice) into this path on the
+TPU backend; everything else falls back to the XLA program.  Off by default;
+tests/test_tpu_compile.py compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -37,6 +37,9 @@ from .pallas_flood import _shift  # one shift/pad primitive for both kernels
 
 _SENT = np.int32(np.iinfo(np.int32).max - 1)
 _NEG = np.int32(-1)
+# Largest slice the whole-slice kernel fits in VMEM on a TPU v5e, from AOT
+# compiles: 512x512 compiles, 768x512 runs out of VMEM
+_MAX_SLICE_ELEMS = 512 * 512
 
 
 def _sweep_min(label, mask_i, axis, reverse):
@@ -242,9 +245,8 @@ def pallas_cc_available(shape, connectivity: int, per_slice: bool) -> bool:
         return False
     if shape[1] % 8 or shape[2] % 128:
         return False
-    # VMEM budget (ADVICE r3): the per-slice kernel holds ~8 full-slice i32
-    # buffers; oversized slices must take the XLA path instead of failing
-    # Mosaic lowering at runtime
-    if shape[1] * shape[2] * 4 * 8 > 12 * 1024 * 1024:
+    # oversized slices take the tiled kernel or the XLA path instead of
+    # failing Mosaic's VMEM check at compile time
+    if shape[1] * shape[2] > _MAX_SLICE_ELEMS:
         return False
     return jax.default_backend() == "tpu"

@@ -28,8 +28,8 @@ ordering inside the gaussian taps (asserted partition-identical, and
 near-exact stage-wise, in tests/test_pallas_dtws.py).
 
 Activation: `CTT_DTWS_MODE=pallas` (TPU backend, per-slice mode, lane-aligned
-slices, no NMS, no pixel pitch).  Off by default until hardware-validated;
-tools/tpu_validate.py measures lowering + perf when a chip is reachable.
+slices up to 64x128 — larger ones exceed VMEM — no NMS, no pixel pitch).
+Off by default; `chip_smoke.py` checks it on the chip against the XLA path.
 """
 
 from __future__ import annotations
@@ -46,6 +46,9 @@ from .filters import _gauss_kernel
 from .pallas_flood import _BIG, _shift, flood_arrays
 
 _BIG_DT = np.float32(1e10)  # ops/dt._BIG
+# Largest slice the fused kernel fits in VMEM on a TPU v5e, from AOT
+# compiles: 64x128 compiles, 128x128 and 256x256 run out of VMEM
+_MAX_SLICE_HW = (64, 128)
 
 
 def _prefix_max(x, axis, reverse):
@@ -63,7 +66,8 @@ def _line_distance_sq(bg, pitch=1.0):
     d_i = pitch · (i − nearest bg index), directional via prefix max over
     bg-carrying indices, two directions, min."""
     h, w = bg.shape
-    iota = lax.broadcasted_iota(jnp.float32, (h, w), 0)
+    # Mosaic builds integer iotas only: make it int32, then cast
+    iota = lax.broadcasted_iota(jnp.int32, (h, w), 0).astype(jnp.float32)
 
     # forward: nearest True at or before i
     last = _prefix_max(jnp.where(bg, iota, -_BIG), 0, False)
@@ -85,13 +89,13 @@ def _parabola_w(g, tile=32):
         jnp.concatenate([g, jnp.full((h, n_pad), _BIG_DT, g.dtype)], axis=1)
         if n_pad else g
     )
-    i_idx = lax.broadcasted_iota(jnp.float32, (w, tile), 0)
+    i_idx = lax.broadcasted_iota(jnp.int32, (w, tile), 0).astype(jnp.float32)
     out = jnp.full((h, w), _BIG_DT, g.dtype)
     for t in range(gp.shape[1] // tile):
         j0 = t * tile
         j_idx = jnp.float32(j0) + lax.broadcasted_iota(
-            jnp.float32, (w, tile), 1
-        )
+            jnp.int32, (w, tile), 1
+        ).astype(jnp.float32)
         diff = i_idx - j_idx  # (w_i, tile_j)
         # static slice via lax.slice_in_dim + expand_dims: the jnp mixed
         # None+slice indexing path lowers to lax.gather, which Mosaic
@@ -366,7 +370,8 @@ def pallas_dt_watershed(
 def pallas_dtws_available(shape, apply_dt_2d, apply_ws_2d, pixel_pitch,
                           nms, sigma_seeds=0.0, sigma_weights=0.0) -> bool:
     """Gate: opted in (CTT_DTWS_MODE=pallas / force_dtws_mode), per-slice
-    mode, 3d, no pitch/NMS, TPU backend, lane-aligned slices, and gaussian
+    mode, 3d, no pitch/NMS, TPU backend, lane-aligned slices that fit in
+    VMEM (``_MAX_SLICE_HW``), and gaussian
     radii strictly inside the slice — the kernel's reflect padding clamps
     at the edge where np.pad(mode="symmetric") cycles, so radii reaching
     across a full axis would silently diverge from the XLA path."""
@@ -380,12 +385,7 @@ def pallas_dtws_available(shape, apply_dt_2d, apply_ws_2d, pixel_pitch,
         return False
     if shape[1] % 8 or shape[2] % 128:
         return False
-    # VMEM budget (ADVICE r3): _parabola_w materializes an (H, W, 32) f32
-    # cost tensor plus ~a dozen full-slice f32 temporaries; slices whose
-    # working set exceeds the ~16 MB VMEM would fail Mosaic lowering at
-    # runtime inside the gated dt_watershed instead of falling back
-    vmem = shape[1] * shape[2] * 4 * (32 + 12)
-    if vmem > 12 * 1024 * 1024:
+    if shape[1] > _MAX_SLICE_HW[0] or shape[2] > _MAX_SLICE_HW[1]:
         return False
     for sigma in (sigma_seeds, sigma_weights):
         if sigma and sigma > 0:

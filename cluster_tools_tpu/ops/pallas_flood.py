@@ -24,9 +24,9 @@ no data reorientation anywhere.
 
 Activation: `CTT_FLOOD_MODE=pallas` opts the per-slice flood into this kernel
 on the TPU backend for lane-aligned slice shapes (H multiple of 8, W multiple
-of 128); everything else falls back to the XLA path.  Off by default until
-hardware-validated (tools/tpu_validate.py measures it when a chip is
-reachable — Mosaic lowering cannot be exercised on the CPU interpreter).
+of 128) that fit in VMEM; everything else falls back to the XLA path.  Off by
+default.  Mosaic lowering cannot be exercised on the CPU interpreter:
+tests/test_tpu_compile.py compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -44,6 +44,9 @@ from .watershed import _minlex  # one source of truth for the tie-break rule
 _BIG = np.float32(3.0e38)
 _NEG = np.float32(-3.0e38)
 _BIG_DIST = np.int32(np.iinfo(np.int32).max - 1)
+# Largest slice the whole-slice kernel fits in VMEM on a TPU v5e, from AOT
+# compiles: 320x512 compiles, 384x512 runs out of VMEM
+_MAX_SLICE_ELEMS = 320 * 512
 
 
 def _shift(x, d, axis, reverse, fill):
@@ -265,7 +268,8 @@ def flood_tiles_warm(hmap, seeds, mask, tile_hw, interpret: bool = False):
 def pallas_flood_tiled_available(shape, per_slice: bool, tile) -> bool:
     """True when the tiled Pallas warm start applies: opted in
     (CTT_FLOOD_MODE=pallas), 3d volume, TPU backend, and the flood tile's
-    in-plane extent exactly tiles a lane-aligned slice.  Valid for both 2d
+    in-plane extent exactly tiles a lane-aligned slice and fits in VMEM
+    (the whole-slice kernel's bound).  Valid for both 2d
     and 3d floods (in-tile paths are real paths either way); the whole-slice
     kernel is preferred when it applies (``pallas_flood_available``)."""
     from . import _backend
@@ -277,13 +281,16 @@ def pallas_flood_tiled_available(shape, per_slice: bool, tile) -> bool:
     th, tw = int(tile[1]), int(tile[2])
     if th % 8 or tw % 128 or shape[1] % th or shape[2] % tw:
         return False
+    if th * tw > _MAX_SLICE_ELEMS:
+        return False
     return jax.default_backend() == "tpu"
 
 
 def pallas_flood_available(shape, per_slice: bool) -> bool:
     """True when the Pallas flood applies: opted in (CTT_FLOOD_MODE=pallas or
     a ``force_flood_mode('pallas')`` scope), per-slice mode, 3d volume, TPU
-    backend, lane-aligned slice shape.
+    backend, lane-aligned slice shape whose flood state fits in VMEM.
+    Larger slices take the tiled warm start or the XLA path.
 
     Evaluated at TRACE time (this runs inside jitted callers): a shape that
     was already compiled keeps its path until the jit caches are cleared —
@@ -296,5 +303,7 @@ def pallas_flood_available(shape, per_slice: bool) -> bool:
     if not per_slice or len(shape) != 3:
         return False
     if shape[1] % 8 or shape[2] % 128:
+        return False
+    if shape[1] * shape[2] > _MAX_SLICE_ELEMS:
         return False
     return jax.default_backend() == "tpu"

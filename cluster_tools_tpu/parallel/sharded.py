@@ -28,6 +28,7 @@ Tested on the 8-virtual-device CPU mesh against the scipy oracle
 
 from __future__ import annotations
 
+import warnings
 from functools import partial
 
 import jax
@@ -35,46 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-import inspect as _inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; resolve the installed spelling once so every collective in
-# parallel/ runs on either API
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *args, check_vma=None, **kwargs):
-    """``jax.shard_map`` with the check kwarg translated to the installed
-    jax's spelling (``check_vma`` >= 0.7, ``check_rep`` before).  On the
-    ``check_rep`` API the check defaults OFF: that generation of the
-    replication checker has no rules for ``while_loop``/``scan`` and
-    rejects every fixpoint kernel in this module."""
-    if check_vma is None and _SHARD_MAP_CHECK_KW == "check_rep":
-        check_vma = False
-    if check_vma is not None:
-        kwargs[_SHARD_MAP_CHECK_KW] = check_vma
-    return _shard_map_impl(f, *args, **kwargs)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` where it exists; the classic constant-folded
-    ``psum(1, axis)`` idiom on older jax."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-import warnings
 
 from .. import faults
 from ..obs import metrics as obs_metrics
@@ -129,7 +90,7 @@ def _neighbor_planes(plane, axis_name, direction):
     +z neighbor (direction=-1) along the mesh ring; shards with no such
     neighbor receive zeros (lax.ppermute semantics), which callers mask out
     via the exchanged mask plane."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if direction > 0:
         perm = [(i, i + 1) for i in range(n - 1)]
     else:
@@ -151,7 +112,7 @@ def halo_exchange(x, halo: int, axis_name: str, fill=0):
     z_local = x.shape[0]
     hops = -(-halo // z_local)  # ceil
     idx = lax.axis_index(axis_name)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
 
     def gather(direction):
         if hops == 1:
@@ -329,11 +290,15 @@ def _sharded_cc(mask, connectivity, axis_name, mesh):
         label = apply_value_roots(label, vals, root_vals)
         return jnp.where(m, label, jnp.int32(-1))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=P(axis_name),
         out_specs=P(axis_name),
+        # the ops/cc.py sweeps seed their scan carries from shape constants,
+        # which the varying-manual-axes check sees as replicated values
+        # meeting per-shard ones; every value here is per-shard
+        check_vma=False,
     )
     return fn(mask)
 
@@ -445,7 +410,7 @@ def _sharded_flood(hmap, seeds, mask, axis_name, mesh):
         )
         return jnp.where(m, label, 0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),

@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..obs import trace as obs_trace
 from ..ops.rag import HIST_BINS, QUANTILES
 from .mesh import get_mesh, put_global
-from .sharded import _neighbor_planes, shard_map
+from .sharded import _neighbor_planes
 
 _BIG_ID = np.int32(np.iinfo(np.int32).max)
 
@@ -237,7 +237,7 @@ def _sharded_rag(labels, values, max_edges, hist_bins, axis_name, mesh,
         )
         return m_u, m_v, feats, m_hist, n_edges, n_local_max, n_true_max
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),

@@ -46,7 +46,7 @@ from ..ops.dt import _BIG as _DT_BIG
 from ..ops.dt import _parabola_pass
 from ..ops.filters import _gauss_kernel
 from .mesh import get_mesh
-from .sharded import _neighbor_planes, axis_size, halo_exchange, shard_map
+from .sharded import _neighbor_planes, halo_exchange
 
 
 def _directional_z_distance(bg, axis_name, reverse):
@@ -70,7 +70,7 @@ def _directional_z_distance(bg, axis_name, reverse):
         d, _ = state
         # the neighbor's far-plane distance, +1 for the boundary hop
         carry = _neighbor_planes(d[-1], axis_name, +1 * direction)
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         idx = lax.axis_index(axis_name)
         edge = idx == (0 if direction > 0 else n - 1)
         carry = jnp.where(edge, jnp.full_like(carry, _DT_BIG), carry)
@@ -214,7 +214,7 @@ def _stage_a(
             hmap = _sharded_gaussian_z(hmap, sigma_weights, axis_name, z_valid)
         return fg & valid, maxima, hmap
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=P(axis_name),
         out_specs=(P(axis_name),) * 3, check_vma=False,
     )(x)
@@ -332,7 +332,7 @@ def sharded_dt_watershed_2d(
         # unique pass for a value production callers discard
         return jnp.where(lab > 0, lab + off, 0), jnp.max(lab)[None]
 
-    labels_d, n_per_shard = shard_map(
+    labels_d, n_per_shard = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=P(axis_name),

@@ -30,9 +30,9 @@ DEFAULT_GLOBAL_CONFIG: Dict[str, Any] = {
     "retry_failure_fraction": 0.5,
     # None resolves, in order: CTT_DEVICE_BATCH env, the measured pin in
     # tools/chip_modes.json (backend-tagged), then the backend default —
-    # 1 block/dispatch on XLA-CPU (vmapped while_loops run max-over-batch
-    # rounds — measured ~2x slower than sequential singles on one core),
-    # 8 on accelerators (amortizes dispatch latency)
+    # None: a measured pin, else 1 block per device per dispatch
+    # (runtime/executor.py DEVICE_BATCH: vmapped while_loops run the
+    # slowest block's rounds for the whole batch)
     "device_batch_size": None,
     # batches in flight on the tpu target: depth d overlaps batch i+1's host
     # chunk IO with batch i's device execution (1 = serial loop)

@@ -112,17 +112,26 @@ def profiler_trace(config: Dict[str, Any]):
     return jax.profiler.trace(profile_dir)
 
 
+# blocks per device dispatch when neither the config nor a pin sets it.
+# A vmapped batch runs its while_loops for the slowest block: on one core
+# of XLA-CPU two singles beat a pair ~2x, and on a TPU v5e the components
+# program at block (50, 512, 512) took 7.6 s for one block but more than
+# 300 s for a batch of 8.  tests/test_tpu_compile.py holds the watershed
+# program at this batch to one v5e's HBM.
+DEVICE_BATCH = 1
+
+
 def resolve_batch_size(config: Dict[str, Any]) -> int:
     """Blocks per device dispatch: the ``device_batch_size`` config knob,
-    else the measured pin (CTT_DEVICE_BATCH / chip pin file), else the
-    backend-aware default — times the visible device count.  Shared by the
+    else the measured pin (CTT_DEVICE_BATCH / chip pin file), else
+    ``DEVICE_BATCH`` — times the visible device count.  Shared by the
     TpuExecutor and the fused-chain runner (ctt-stream) so a fused and an
     unfused run chunk the block list identically."""
     bs_conf = config.get("device_batch_size")
     if bs_conf is None:
         # measured pin (env var, else the backend-tagged pin file —
         # tools/chip_session.py writes CTT_DEVICE_BATCH), else the
-        # backend-aware default; malformed pins degrade to the default
+        # default; malformed pins degrade to the default
         # like every other CTT_* switch
         from ..ops import _backend
 
@@ -130,10 +139,7 @@ def resolve_batch_size(config: Dict[str, Any]) -> int:
         try:
             bs_conf = int(pin)
         except (TypeError, ValueError):
-            import jax
-
-            # backend-aware default: see runtime/config.py
-            bs_conf = 1 if jax.default_backend() == "cpu" else 8
+            bs_conf = DEVICE_BATCH
     batch_size = max(int(bs_conf), 1)
     devices = config.get("devices")
     if devices and devices != "global":

@@ -47,12 +47,10 @@ class ExecutionContext:
 
     def __init__(
         self,
-        compile_cache_path: Optional[str] = None,
         chunk_cache_mb: Optional[float] = None,
         role: Optional[str] = None,
         hbm_cache_mb: Optional[float] = None,
     ):
-        self._compile_cache_path = compile_cache_path
         self._chunk_cache_mb = chunk_cache_mb
         self._hbm_cache_mb = hbm_cache_mb
         self._role = role
@@ -70,9 +68,7 @@ class ExecutionContext:
         from ..obs import heartbeat as obs_heartbeat
         from ..utils.compile_cache import enable_compile_cache
 
-        self.compile_cache_dir = enable_compile_cache(
-            self._compile_cache_path
-        )
+        self.compile_cache_dir = enable_compile_cache()
         if self._chunk_cache_mb is not None:
             from ..utils import store
 

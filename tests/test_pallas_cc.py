@@ -1,7 +1,7 @@
 """Pallas per-slice CC + device z-merge vs the XLA CC and scipy.
 
-Mirrors tests/test_pallas_flood.py: the Mosaic lowering itself can only be
-exercised on hardware (tools/tpu_validate.py); here the kernel runs in the
+Mirrors tests/test_pallas_flood.py: the Mosaic lowering is compiled for a
+described chip in tests/test_tpu_compile.py; here the kernel runs in the
 CPU interpreter, which executes identical kernel logic."""
 
 import numpy as np
@@ -113,7 +113,7 @@ class TestPallasCC:
             assert not pallas_cc_available(shape, 3, False)
             assert not pallas_cc_available((6, 16, 100), 1, False)
             assert not pallas_cc_available((16, 128), 1, False)
-            # VMEM budget (ADVICE r3): oversized slices take the XLA path
+            # VMEM bound: oversized slices take the XLA path
             assert not pallas_cc_available((4, 1024, 1024), 1, False)
 
     def test_empty_and_full(self):

@@ -1,6 +1,6 @@
 """Fused per-slice DT-watershed kernel vs the XLA pipeline.
 
-Interpreter-mode (Mosaic lowering is hardware-only — tools/tpu_validate.py).
+Interpreter-mode (Mosaic lowering: tests/test_tpu_compile.py).
 The contract is BITWISE equality with
 ``dt_watershed(apply_dt_2d=True, apply_ws_2d=True)``: same EDT arithmetic,
 same gaussian taps, same maxima rule, same CC numbering (minimal-flat-index

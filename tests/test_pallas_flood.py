@@ -1,7 +1,7 @@
 """Pallas per-slice flood: exact equivalence with the XLA flood fixpoint.
 
-Runs the kernel through the Pallas CPU interpreter (Mosaic lowering itself
-needs hardware — tools/tpu_validate.py covers that); equivalence here is
+Runs the kernel through the Pallas CPU interpreter (Mosaic lowering is
+compiled for a described chip in tests/test_tpu_compile.py); equivalence here is
 *exact label equality*, since both paths compute the same lexicographic
 (pass-height, hops, label) fixpoint with identical tie-breaking.
 """

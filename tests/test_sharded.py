@@ -69,13 +69,11 @@ def test_halo_exchange_roundtrip(rng):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from cluster_tools_tpu.parallel.sharded import shard_map
-
     mesh = get_mesh()
     x = np.arange(24 * 4 * 4, dtype=np.float32).reshape(24, 4, 4)
     xd = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("data")))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(halo_exchange, halo=1, axis_name="data", fill=-1.0),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
     )
@@ -112,13 +110,11 @@ def test_halo_exchange_multi_hop():
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from cluster_tools_tpu.parallel.sharded import shard_map
-
     mesh = get_mesh()
     x = np.arange(16 * 2 * 2, dtype=np.float32).reshape(16, 2, 2)  # Zl = 2
     xd = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("data")))
     halo = 5  # needs 3 hops at z_local = 2
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(halo_exchange, halo=halo, axis_name="data", fill=-1.0),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
     )
