@@ -298,11 +298,9 @@ def one_chip_pallas(raw):
         pallas_cc_tile,
         pallas_connected_components_tiled,
     )
-    from cluster_tools_tpu.ops.pallas_dtws import _MAX_SLICE_HW
     from cluster_tools_tpu.ops.pallas_flood import _MAX_SLICE_ELEMS
     from cluster_tools_tpu.ops.watershed import (
         dt_seeds,
-        dt_watershed,
         seeded_watershed,
     )
 
@@ -343,12 +341,6 @@ def one_chip_pallas(raw):
             hm, sd, m, per_slice=True, coarse_tile=t)
         case(f"{kernel} {tuple(hmap.shape)}", _backend.force_flood_mode,
              compile_once(flood, hmap, seeds, fg)[1], flood, hmap, seeds, fg)
-
-    # fused DT-watershed at the largest slice its gate admits
-    x = jnp.asarray(raw[:32, : _MAX_SLICE_HW[0], : _MAX_SLICE_HW[1]])
-    dtws = lambda v: dt_watershed(v, threshold=THRESHOLD)  # noqa: E731
-    case(f"dtws_slices {tuple(x.shape)}", _backend.force_dtws_mode,
-         compile_once(dtws, x)[1], dtws, x)
 
 
 def four_chip_collective(root, path, raw):

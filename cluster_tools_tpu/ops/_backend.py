@@ -5,7 +5,7 @@ scans (ops/dt.py) all choose between log-depth formulations
 (``lax.associative_scan`` / ``lax.cummax`` — win on dispatch/latency-bound
 TPUs) and sequential carry chains (O(n) work — win on work-bound XLA-CPU).
 Further opt-in kernel switches route whole pipelines to Pallas
-(flood/cc/dtws) or to the device MWS formulation.  One registry keeps every
+(flood/cc) or to the device MWS formulation.  One registry keeps every
 switch on the same contract:
 
   * default: by env var (``CTT_<KIND>_MODE``), else a backend-tagged pin
@@ -32,7 +32,6 @@ _ENV = {
     "sweep": "CTT_SWEEP_MODE",
     "flood": "CTT_FLOOD_MODE",
     "cc": "CTT_CC_MODE",
-    "dtws": "CTT_DTWS_MODE",
     "mws": "CTT_MWS_MODE",
 }
 
@@ -164,12 +163,6 @@ def use_coarse_cc() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def use_pallas_dtws() -> bool:
-    """Whether the per-slice DT-watershed uses the fused Pallas kernel
-    (ops/pallas_dtws.py, CTT_DTWS_MODE=pallas)."""
-    return _mode("dtws") == "pallas"
-
-
 def use_mws_device() -> bool:
     """Whether graph-domain MWS solves route to the parallel-greedy device
     kernel (ops/mws_device.py, CTT_MWS_MODE=device) instead of host C++."""
@@ -189,11 +182,6 @@ def force_flood_mode(mode):
 def force_cc_mode(mode):
     """Scoped CC-mode override ('coarse' | 'flat' | 'pallas' | 'slices')."""
     return _force("cc", mode)
-
-
-def force_dtws_mode(mode):
-    """Scoped DT-watershed-mode override ('pallas' | 'xla')."""
-    return _force("dtws", mode)
 
 
 def force_mws_mode(mode):

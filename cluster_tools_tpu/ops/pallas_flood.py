@@ -133,8 +133,7 @@ def _sweep_assign(dist, label, alt, hmap, is_seed, mask, axis, reverse):
 
 def flood_arrays(hmap, seeds, mask):
     """Both flood phases to their fixpoint over in-VMEM (H, W) arrays —
-    shared by the standalone flood kernel and the fused DT-watershed kernel
-    (ops/pallas_dtws.py)."""
+    the body of the whole-slice flood kernel."""
     seeds = jnp.where(mask, seeds, 0)
     is_seed = seeds > 0
 

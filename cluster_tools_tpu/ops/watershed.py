@@ -804,20 +804,6 @@ def dt_watershed(
         # pitch only applies to the 3d distance transform
         raise ValueError("pixel_pitch requires apply_dt_2d=False")
 
-    from .pallas_dtws import pallas_dt_watershed, pallas_dtws_available
-
-    if pallas_dtws_available(
-        input_.shape, apply_dt_2d, apply_ws_2d, pixel_pitch,
-        non_maximum_suppression, sigma_seeds, sigma_weights,
-    ):
-        # CTT_DTWS_MODE=pallas: the whole per-slice pipeline as ONE fused
-        # VMEM kernel per slice — bitwise-identical labels (tested)
-        return pallas_dt_watershed(
-            input_, mask=mask, valid=valid, threshold=threshold,
-            sigma_seeds=sigma_seeds, sigma_weights=sigma_weights,
-            alpha=alpha, size_filter=size_filter, invert_input=invert_input,
-        )
-
     x = input_.astype(jnp.float32)
     if invert_input:
         x = 1.0 - x

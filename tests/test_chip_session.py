@@ -76,18 +76,6 @@ def test_dtws_only_sweep_fallback():
     )["CTT_SWEEP_MODE"] == "seq"
 
 
-def test_dtws_pallas_gate():
-    base = {
-        "dtws_assoc_ms": 10.0, "dtws_seq_ms": 12.0,
-        "cc_assoc_ms": 10.0, "cc_seq_ms": 12.0,
-    }
-    assert derive_modes(
-        {**base, "pallas_dtws_exact": True, "pallas_dtws_wins": True}
-    )["CTT_DTWS_MODE"] == "pallas"
-    assert "CTT_DTWS_MODE" not in derive_modes(
-        {**base, "pallas_dtws_exact": False, "pallas_dtws_wins": True})
-
-
 def test_missing_measurements_pin_nothing():
     assert derive_modes({}) == {}
 

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from cluster_tools_tpu.ops import _backend, pallas_cc, pallas_dtws, pallas_flood
+from cluster_tools_tpu.ops import _backend, pallas_cc, pallas_flood
 
 V5E_HBM_BYTES = 16 * 10**9  # Google Cloud "TPU v5e": 16 GB of HBM per chip
 BLOCK = (50, 512, 512)  # runtime/config.py default block_shape
@@ -55,7 +55,6 @@ def _flood_args(sh, shape):
 
 _FLOOD_SLICE = (BLOCK[0], pallas_flood._MAX_SLICE_ELEMS // 512, 512)
 _CC_TILE = pallas_cc.pallas_cc_tile(BLOCK)
-_DTWS_SLICE = (32,) + pallas_dtws._MAX_SLICE_HW
 
 KERNELS = {
     # whole-slice CC at the production block, the largest its gate admits
@@ -69,9 +68,6 @@ KERNELS = {
     # the largest flood tile the tiled gate admits
     "flood_tiles_warm": lambda sh: pallas_flood.flood_tiles_warm.lower(
         *_flood_args(sh, _FLOOD_SLICE), tile_hw=_FLOOD_SLICE[1:]),
-    "dtws_slices": lambda sh: pallas_dtws.dtws_slices.lower(
-        _spec(sh, _DTWS_SLICE, jnp.float32), _spec(sh, _DTWS_SLICE, jnp.bool_),
-        _spec(sh, _DTWS_SLICE, jnp.bool_)),
 }
 
 
