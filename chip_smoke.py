@@ -6,17 +6,19 @@
 
 Drives the system through its user entry point, ``runtime.build()`` with
 ``target: "tpu"``, on a seeded CREMI-calibrated boundary volume at the
-geometry of CREMI sample A (125x1250x1250 voxels) with the production
-block shape [50, 512, 512], and checks every output against an exact host
-oracle (scipy / numpy).  Every run is traced (``CTT_TRACE_DIR``), and a run
-that went through a fallback (collective to local kernel, fused chain to
-task by task, batch to block by block) fails.
+in-plane size of CREMI sample A (1250x1250; z cut, see below) with the
+production block shape [50, 512, 512], and checks every output against
+an exact host oracle (scipy / numpy).  Every run is traced
+(``CTT_TRACE_DIR``), and a run that went through a fallback (collective
+to local kernel, fused chain to task by task, batch to block by block)
+fails.
 
 One chip: ThresholdedComponentsWorkflow, WatershedWorkflow and
 MulticutSegmentationWorkflow(skip_ws=True), then each Pallas kernel once,
-compared bit for bit with the XLA path.  Four chips: the sharded
-components path and the fused sharded watershed + problem multicut, plus
-the sharded seeded flood against the single-device flood.
+compared bit for bit with the XLA path.  Four chips: block batches of
+ThresholdedComponentsWorkflow, one block per chip; the sharded components
+path; the sharded seeded flood against the single-device flood; the fused
+sharded watershed + problem multicut.
 
 Refuses to run without a TPU.  The last stdout line is one JSON object,
 ``{"ok": true, "device": {"platform", "kind", "count"}}``; it is printed
@@ -40,6 +42,12 @@ import time
 FULL_SHAPE = (125, 1250, 1250)
 SHAPE = (50, 1250, 1250)
 BLOCK = (50, 512, 512)  # runtime/config.py default block_shape
+# Four chips cost four times as much per second, and the collective
+# programs compile cold: the four-chip phase takes the first 16 slices of
+# the same volume (4 per chip).
+Z_FOUR_CHIPS = 16
+# static edge capacity of the collective RAG table for that volume
+MAX_EDGES_4 = 1 << 18
 SEED = 0
 THRESHOLD = 0.5  # ThresholdedComponents' default threshold
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -109,18 +117,19 @@ def print_versions(dev):
         f"libtpu {libtpu}")
 
 
-def write_volume(root):
+def write_volume(root, z):
+    """The seeded volume at SHAPE, its first ``z`` slices written as n5."""
     from cluster_tools_tpu.utils import file_reader
     from cluster_tools_tpu.utils.synthetic import make_volume
 
     t0 = time.perf_counter()
-    raw = make_volume(SHAPE, seed=SEED)
+    raw = make_volume(SHAPE, seed=SEED)[:z]
     path = os.path.join(root, "data.n5")
     file_reader(path).create_dataset("raw", data=raw, chunks=BLOCK)
-    if SHAPE != FULL_SHAPE:
-        log(f"cut: z extent {FULL_SHAPE[0]} -> {SHAPE[0]} (CREMI sample A is "
+    if raw.shape != FULL_SHAPE:
+        log(f"cut: z extent {FULL_SHAPE[0]} -> {z} (CREMI sample A is "
             f"{FULL_SHAPE}); block shape and in-plane size uncut")
-    log(f"volume {SHAPE} seed {SEED}: {(raw > THRESHOLD).mean():.4f} "
+    log(f"volume {raw.shape} seed {SEED}: {(raw > THRESHOLD).mean():.4f} "
         f"boundary fraction, written as n5 with chunks {BLOCK} "
         f"({time.perf_counter() - t0:.1f} s)")
     return path, raw
@@ -139,6 +148,7 @@ def run_workflow(name, wf, tmp_folder):
         f"{k}={v}" for k, v in sorted(task_breakdown(tmp_folder).items())
     )
     log(f"{name}: wall {wall:.1f} s; task busy seconds: {walls}")
+    check_fallback_counters(name)
 
 
 # -- oracles ----------------------------------------------------------------
@@ -201,13 +211,20 @@ def check_coarsening(ws, seg, what):
     check(len(pairs) == n_frag, f"{what} segmentation coarsens the fragments")
 
 
-def check_no_fallback():
-    from cluster_tools_tpu.obs import metrics, trace
+def check_fallback_counters(what):
+    from cluster_tools_tpu.obs import metrics
 
-    trace.flush()
     counters = metrics.snapshot()["counters"]
     for name in ("sharded.fallback_local", "stream.fallbacks"):
-        check(counters.get(name, 0) == 0, f"counter {name} == 0")
+        check(counters.get(name, 0) == 0, f"{what}: counter {name} == 0")
+    return counters
+
+
+def check_no_fallback():
+    from cluster_tools_tpu.obs import trace
+
+    trace.flush()
+    counters = check_fallback_counters("all phases")
     n_spans = n_fallback = 0
     for path in glob.glob(os.path.join(trace.run_dir(), "spans.*.jsonl")):
         with open(path) as f:
@@ -226,10 +243,10 @@ def check_no_fallback():
 # -- phases -----------------------------------------------------------------
 
 
-def write_global_config(config_dir, devices=None):
+def write_global_config(config_dir, devices=None, **extra):
     from cluster_tools_tpu.runtime import config as cfg
 
-    conf = {"block_shape": list(BLOCK), "target": "tpu"}
+    conf = {"block_shape": list(BLOCK), "target": "tpu", **extra}
     if devices is not None:
         conf["devices"] = devices
     cfg.write_global_config(config_dir, conf)
@@ -344,7 +361,8 @@ def one_chip_pallas(raw):
 
 
 def four_chip_collective(root, path, raw):
-    import jax
+    """The collective path over four chips, cheapest phase first, each
+    checked by the same host oracles as on one chip."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -357,16 +375,38 @@ def four_chip_collective(root, path, raw):
         MulticutSegmentationWorkflow,
         ThresholdedComponentsWorkflow,
     )
-    from cluster_tools_tpu.workflows.watershed import WatershedWorkflow
 
     devices = list(range(4))
+    tmp = lambda name: os.path.join(root, f"tmp_{name}")  # noqa: E731
+    mesh = mesh_mod.get_mesh(mesh_mod.resolve_devices({"devices": devices}))
+    check(mesh.devices.size == 4, "collective mesh spans 4 devices")
+
+    # Block batches, data-parallel over the mesh: 2x2 blocks, one per chip.
+    # The full 3x3 grid would end on a one-block batch, which put_sharded
+    # places on one device and last_batch_sharding then reports.
+    roi_end = [raw.shape[0], 2 * BLOCK[1], 2 * BLOCK[2]]
+    roi_dir = os.path.join(root, "configs_roi")
+    write_global_config(roi_dir, devices,
+                        roi_begin=[0, 0, 0], roi_end=roi_end)
+    mesh_mod._LAST_BATCH_SHARDING = None
+    run_workflow("ThresholdedComponentsWorkflow(block batches, 2x2 blocks)",
+                 ThresholdedComponentsWorkflow(
+                     tmp("cc_blocks"), roi_dir, input_path=path,
+                     input_key="raw", output_path=path,
+                     output_key="components_blocks",
+                 ), tmp("cc_blocks"))
+    batch = mesh_mod.last_batch_sharding()
+    check(batch is not None and len(batch.device_set) == 4,
+          f"block batch spanned 4 devices ({batch})")
+    roi = tuple(slice(0, e) for e in roi_end)
+    check_components(file_reader(path, "r")["components_blocks"][roi],
+                     raw[roi], "block-batch components (2x2 blocks)")
+
     config_dir = os.path.join(root, "configs")
     write_global_config(config_dir, devices)
     # the collective edge table is static-shape: size it for this volume
-    cfg.write_config(config_dir, "sharded_ws_problem", {"max_edges": 1 << 21})
-    tmp = lambda name: os.path.join(root, f"tmp_{name}")  # noqa: E731
-    mesh_mod._LAST_BATCH_SHARDING = None
-
+    cfg.write_config(config_dir, "sharded_ws_problem",
+                     {"max_edges": MAX_EDGES_4})
     run_workflow("ThresholdedComponentsWorkflow(sharded)",
                  ThresholdedComponentsWorkflow(
                      tmp("cc"), config_dir, input_path=path, input_key="raw",
@@ -374,6 +414,23 @@ def four_chip_collective(root, path, raw):
                  ), tmp("cc"))
     check_components(file_reader(path, "r")["components"][:], raw,
                      "sharded components")
+
+    # sharded flood vs the single-device flood, bit for bit, at the shape
+    # the sharded watershed below floods (so it reuses this compile)
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(SEED)
+    sd = np.zeros(raw.shape, np.int32)
+    pts = rng.choice(raw.size, 64, replace=False)
+    sd.reshape(-1)[pts] = np.arange(1, 65, dtype=np.int32)
+    fg = raw < THRESHOLD
+    got = sharded_seeded_watershed(raw, sd, fg, mesh=mesh)
+    check(len(got.sharding.device_set) == 4,
+          "sharded flood output spans 4 devices")
+    want = seeded_watershed(jnp.asarray(raw), jnp.asarray(sd),
+                            jnp.asarray(fg))
+    check((np.asarray(got) == np.asarray(want)).all(),
+          f"sharded_seeded_watershed {raw.shape} == seeded_watershed, "
+          f"bit for bit ({time.perf_counter() - t0:.1f} s)")
 
     run_workflow("MulticutSegmentationWorkflow(sharded)",
                  MulticutSegmentationWorkflow(
@@ -386,38 +443,6 @@ def four_chip_collective(root, path, raw):
     check_problem(tmp("mc"), ws, "sharded problem")
     seg = file_reader(path, "r")["segmentation"][:]
     check_coarsening(ws, seg.astype(np.uint64), "sharded multicut")
-
-    # what the collective front is compared with: the block-parallel
-    # watershed, its block batches data-parallel over the same four chips
-    run_workflow("WatershedWorkflow(block batches)", WatershedWorkflow(
-        tmp("ws"), config_dir, input_path=path, input_key="raw",
-        output_path=path, output_key="watershed_blocks",
-    ), tmp("ws"))
-    ws_blocks = file_reader(path, "r")["watershed_blocks"][:]
-    log(f"block-parallel watershed: {len(np.unique(ws_blocks)) - 1} "
-        f"fragments (collective front: {len(np.unique(ws[ws > 0]))})")
-    batch = mesh_mod.last_batch_sharding()
-    check(batch is not None and len(batch.device_set) == 4,
-          f"block batches spanned 4 devices ({batch})")
-    mesh = mesh_mod.get_mesh(mesh_mod.resolve_devices({"devices": devices}))
-    check(mesh.devices.size == 4, "collective mesh spans 4 devices")
-
-    # sharded flood vs the single-device flood, bit for bit
-    t0 = time.perf_counter()
-    hm = raw[:48, :512, :512]  # z divisible by the 4-device mesh
-    rng = np.random.default_rng(SEED)
-    sd = np.zeros(hm.shape, np.int32)
-    pts = rng.integers(0, hm.size, 64)
-    sd.reshape(-1)[pts] = np.arange(1, 65, dtype=np.int32)
-    want = seeded_watershed(jnp.asarray(hm), jnp.asarray(sd))
-    got = sharded_seeded_watershed(hm, sd, mesh=mesh)
-    check(len(got.sharding.device_set) == 4,
-          "sharded flood output spans 4 devices")
-    check((np.asarray(got) == np.asarray(want)).all(),
-          f"sharded_seeded_watershed {hm.shape} == seeded_watershed, "
-          "bit for bit")
-    log(f"sharded flood check {time.perf_counter() - t0:.1f} s "
-        f"({jax.device_count()} devices)")
 
 
 def main(argv=None):
@@ -438,7 +463,8 @@ def main(argv=None):
         from cluster_tools_tpu.utils.compile_cache import enable_compile_cache
 
         log(f"compile cache: {enable_compile_cache()}")
-        path, raw = write_volume(root)
+        z = Z_FOUR_CHIPS if args.chips == 4 else SHAPE[0]
+        path, raw = write_volume(root, z)
         if args.chips == 4:
             four_chip_collective(root, path, raw)
         else:

@@ -107,18 +107,7 @@ def block_edges(labels: np.ndarray, ignore_zero: bool = True) -> np.ndarray:
             pairs.append(np.stack([np.minimum(a, b), np.maximum(a, b)], axis=1))
     if not pairs:
         return np.zeros((0, 2), dtype=labels.dtype)
-    pairs = np.concatenate(pairs, axis=0)
-    if pairs.min() >= 0 and pairs.max() < (1 << 32):
-        # one 64-bit key per pair sorts in the same (u, v) order, far faster
-        # than the row-wise unique on volume-sized pair lists
-        key = np.unique(
-            (pairs[:, 0].astype(np.uint64) << np.uint64(32))
-            | pairs[:, 1].astype(np.uint64)
-        )
-        return np.stack(
-            [key >> np.uint64(32), key & np.uint64(0xFFFFFFFF)], axis=1
-        ).astype(labels.dtype)
-    return np.unique(pairs, axis=0)
+    return np.unique(np.concatenate(pairs, axis=0), axis=0)
 
 
 def _owner_mask(shape, owner_shape) -> Optional[np.ndarray]:

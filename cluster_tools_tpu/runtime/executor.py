@@ -113,11 +113,12 @@ def profiler_trace(config: Dict[str, Any]):
 
 
 # blocks per device dispatch when neither the config nor a pin sets it.
-# A vmapped batch runs its while_loops for the slowest block: on one core
-# of XLA-CPU two singles beat a pair ~2x, and on a TPU v5e the components
-# program at block (50, 512, 512) took 7.6 s for one block but more than
-# 300 s for a batch of 8.  tests/test_tpu_compile.py holds the watershed
-# program at this batch to one v5e's HBM.
+# On a TPU v5e the components program at block (50, 512, 512) took 7.6 s
+# for one block and had not returned after more than 300 s for a batch of
+# 8; why a batch is so much slower than its blocks one at a time is not
+# known yet.  On one core of XLA-CPU two singles beat a pair ~2x.
+# tests/test_tpu_compile.py compiles the watershed program at this batch
+# and at 8 against one v5e's HBM.
 DEVICE_BATCH = 1
 
 

@@ -15,6 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from cluster_tools_tpu.ops import _backend, pallas_cc, pallas_flood
+from cluster_tools_tpu.runtime.executor import DEVICE_BATCH
 
 V5E_HBM_BYTES = 16 * 10**9  # Google Cloud "TPU v5e": 16 GB of HBM per chip
 BLOCK = (50, 512, 512)  # runtime/config.py default block_shape
@@ -85,11 +86,12 @@ def test_pallas_kernel_compiles_to_mosaic(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_block_watershed_program_fits_hbm(one_chip):
+# the default device batch, and 8, the TPU default it replaced
+@pytest.mark.parametrize("batch", sorted({DEVICE_BATCH, 8}))
+def test_block_watershed_program_fits_hbm(one_chip, batch):
     """The per-batch DT-watershed program WatershedTask dispatches, at the
-    production block and the default device batch, with the sweep and CC
-    formulations the TPU backend selects."""
-    from cluster_tools_tpu.runtime.executor import DEVICE_BATCH
+    production block and a device batch of ``batch`` blocks, with the
+    sweep and CC formulations the TPU backend selects."""
     from cluster_tools_tpu.tasks.watershed import (
         WatershedTask,
         _fused_ws_kernel,
@@ -97,13 +99,13 @@ def test_block_watershed_program_fits_hbm(one_chip):
 
     conf = WatershedTask.default_task_config()
     params = tuple(sorted(WatershedTask._kernel_params(conf).items()))
-    shape = (DEVICE_BATCH,) + BLOCK
+    shape = (batch,) + BLOCK
     with _backend.force_sweep_mode("assoc"), _backend.force_cc_mode("coarse"):
         fn = _fused_ws_kernel(params, BLOCK, False, False, None)
         compiled = fn.lower(
             _spec(one_chip, shape, jnp.float32),
             _spec(one_chip, shape, jnp.bool_),
-            _spec(one_chip, (DEVICE_BATCH, 3), jnp.int32),
+            _spec(one_chip, (batch, 3), jnp.int32),
         ).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
