@@ -73,7 +73,7 @@ def main(argv=None) -> int:
     sub = parser.add_subparsers(dest="cmd", required=True)
 
     p_sum = sub.add_parser(
-        "summarize", help="per-task host-IO/device/collective breakdown"
+        "summarize", help="per-task host-IO/host-compute/collective breakdown"
     )
     p_sum.add_argument("run")
     p_sum.add_argument("--json", action="store_true",

@@ -11,12 +11,13 @@ read side:
     shard header — good to cross-process clock skew, which is fine for
     eyeballing concurrency in Perfetto and irrelevant for the summaries.
   * `summarize` — per-task breakdown into distinct buckets: ``host_io``
-    (chunk reads/writes), ``device`` (batched dispatch), ``collective``
-    (mesh programs), ``host`` (other host work).  Bucket sums use *self
-    time* (span duration minus its children's durations), so a device
-    batch that encloses a host-IO read is never double-counted, and
-    ``host_io + device + host > dispatch wall`` is exactly the pipeline
-    overlap (host IO hidden behind device execution).
+    (chunk reads/writes), ``host_compute`` (blocking device dispatches,
+    timed on the host clock — device time itself is in a profiler trace),
+    ``collective`` (mesh programs), ``host`` (other host work).  Bucket
+    sums use *self time* (span duration minus its children's durations),
+    so a batch span that encloses a host-IO read is never double-counted,
+    and ``host_io + host_compute + host > dispatch wall`` is exactly the
+    pipeline overlap (host IO hidden behind device execution).
   * `to_chrome_trace` — Chrome ``trace_event`` JSON (load it in Perfetto
     or ``chrome://tracing``).
   * `diff` — compare two runs task by task and flag wall-clock
@@ -48,7 +49,7 @@ SHARD_GLOB = "spans.p*.jsonl"
 
 # span kinds → summary buckets; structural/bridge kinds are excluded from
 # the bucket sums (see summarize)
-_BUCKETS = {"host_io": "host_io_s", "device": "device_s",
+_BUCKETS = {"host_io": "host_io_s", "host_compute": "host_compute_s",
             "collective": "collective_s"}
 _EXCLUDED_KINDS = {"task", "dispatch", "run", "timing"}
 
@@ -192,7 +193,7 @@ def _task_of(span: dict, by_id: Dict[int, dict]) -> Optional[str]:
 
 def _new_row() -> Dict[str, float]:
     return {
-        "wall_s": 0.0, "host_io_s": 0.0, "device_s": 0.0,
+        "wall_s": 0.0, "host_io_s": 0.0, "host_compute_s": 0.0,
         "collective_s": 0.0, "host_s": 0.0, "dispatch_wall_s": 0.0,
         "overlap_hidden_s": 0.0, "n_spans": 0,
     }
@@ -229,7 +230,7 @@ def summarize(run: Dict[str, Any]) -> Dict[str, Any]:
             row[_BUCKETS.get(kind, "host_s")] += self_t
     for row in tasks.values():
         if row["dispatch_wall_s"] > 0.0:
-            busy = row["host_io_s"] + row["device_s"] + row["host_s"]
+            busy = row["host_io_s"] + row["host_compute_s"] + row["host_s"]
             row["overlap_hidden_s"] = max(0.0, busy - row["dispatch_wall_s"])
     return {
         "run_id": run["run_id"],
@@ -246,7 +247,7 @@ def summarize(run: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def format_summary(summary: Dict[str, Any]) -> str:
-    cols = ["wall_s", "host_io_s", "device_s", "collective_s", "host_s",
+    cols = ["wall_s", "host_io_s", "host_compute_s", "collective_s", "host_s",
             "overlap_hidden_s", "n_spans"]
     names = sorted(
         summary["tasks"],

@@ -52,8 +52,9 @@ __all__ = [
 SHARD_GLOB = "spans.p*.jsonl"
 
 # span names that represent block *execution* (the things the heatmap and
-# progress counters aggregate).  host_io stage spans are excluded: they
-# cover the same blocks again and would double-count.
+# progress counters aggregate; ``block:<task>`` counts as ``block``).
+# host_io stage spans are excluded: they cover the same blocks again and
+# would double-count.
 _BLOCK_SPAN_NAMES = {"block", "block_fallback", "block_batch", "stage_compute"}
 
 _now_wall = time.time  # module-level so tests can fake the reader clock
@@ -198,7 +199,8 @@ class LiveRun:
             if isinstance(attrs.get("grid"), list):
                 info["grid"] = attrs["grid"]
             return
-        if name not in _BLOCK_SPAN_NAMES:
+        if (not isinstance(name, str)
+                or name.split(":", 1)[0] not in _BLOCK_SPAN_NAMES):
             return
         if "block" in attrs:
             bids = [attrs["block"]]

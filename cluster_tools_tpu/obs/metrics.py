@@ -18,7 +18,14 @@ Wired in:
     chunk-aligned encode fast path instead of read-modify-write);
   * ``utils/compile_cache.py`` — ``compile_cache.cache_hits`` /
     ``compile_cache.cache_misses`` via a ``jax.monitoring`` event
-    listener, plus an ``entries_at_enable`` gauge;
+    listener, plus an ``entries_at_enable`` gauge; ``jit.compiles`` /
+    ``jit.compile_s``, the count and seconds of JAX's backend compiles
+    (a load from the persistent cache included), via a duration listener;
+  * ``tasks/base.py`` ``count_block_rounds`` — the block programs' loop
+    rounds, outputs of the programs themselves: ``flood.rounds`` (global
+    altitude + assignment loops of every flood), ``flood.tile_rounds``
+    (tile warm-start loops), ``cc.rounds`` (CC fixpoint loops) and
+    ``blocks.computed`` (the blocks they cover);
   * ``runtime/task.py`` — ``task.blocks_failed`` / ``task.blocks_retried``;
   * ``faults/`` + the resilience paths it validates (ctt-fault) —
     ``faults.injected`` / ``faults.injected.<site>`` (every fired
@@ -129,12 +136,16 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_misses": "compile_cache.cache_misses",
     "/jax/compilation_cache/tasks_using_cache": "compile_cache.tasks_using_cache",
 }
+# the duration event JAX records around each backend compile (or load of
+# an executable from the persistent cache), jax/_src/dispatch.py
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def install_compile_cache_listener() -> bool:
-    """Count persistent-compile-cache hits/misses via ``jax.monitoring``
-    (idempotent).  Returns False when the monitoring API is unavailable —
-    the cache keeps working, only the metric is absent."""
+    """Count persistent-compile-cache hits/misses and backend compiles via
+    ``jax.monitoring`` (idempotent).  Returns False when the monitoring API
+    is unavailable — the cache keeps working, only the metrics are
+    absent."""
     global _CACHE_LISTENER_INSTALLED
     if _CACHE_LISTENER_INSTALLED:
         return True
@@ -148,8 +159,14 @@ def install_compile_cache_listener() -> bool:
         if name is not None:
             inc(name)
 
+    def _duration_listener(event: str, duration: float, **kwargs) -> None:
+        if event == _COMPILE_EVENT:
+            inc("jit.compiles")
+            inc("jit.compile_s", duration)
+
     try:
         monitoring.register_event_listener(_listener)
+        monitoring.register_event_duration_secs_listener(_duration_listener)
     except Exception:  # pragma: no cover - API drift must not break callers
         return False
     _CACHE_LISTENER_INSTALLED = True

@@ -55,6 +55,16 @@ COUNTERS = frozenset({
     "compile_cache.cache_hits",
     "compile_cache.cache_misses",
     "compile_cache.tasks_using_cache",
+    # utils/compile_cache.py — jax.monitoring backend-compile durations
+    # (a load from the persistent cache included): count and seconds
+    "jit.compiles",
+    "jit.compile_s",
+    # tasks/base.py count_block_rounds — loop rounds the block programs
+    # return beside their labels (ops/watershed.py, ops/cc.py)
+    "blocks.computed",          # blocks of the programs that counted
+    "flood.rounds",             # global altitude + assignment loop rounds
+    "flood.tile_rounds",        # tile warm-start loop rounds
+    "cc.rounds",                # CC fixpoint loop rounds
     # runtime/task.py — retry machinery
     "task.blocks_failed",
     "task.blocks_retried",

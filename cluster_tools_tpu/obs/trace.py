@@ -258,6 +258,7 @@ import itertools
 import json
 import os
 import socket
+import sys
 import threading
 import time
 from typing import Any, Dict, IO, Optional, Tuple
@@ -471,8 +472,20 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name``, so the span also
+    lands in a profiler trace's host plane, on the device trace's clock;
+    None in a process that has not imported jax (no profiler can run
+    there, and a span never imports it)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name)
+
+
 class _Span:
-    __slots__ = ("name", "kind", "attrs", "sid", "parent", "t0", "_st")
+    __slots__ = ("name", "kind", "attrs", "sid", "parent", "t0", "_st",
+                 "_ann")
 
     def __init__(self, st: _RunState, name: str, kind: str, attrs):
         self._st = st
@@ -482,6 +495,7 @@ class _Span:
         self.sid = st.next_span_id()
         self.parent = None
         self.t0 = 0.0
+        self._ann = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -491,11 +505,16 @@ class _Span:
         if stack:
             self.parent = stack[-1].sid
         stack.append(self)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._st.stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -522,12 +541,15 @@ def span(name: str, kind: str = "host", **attrs):
     """Context manager recording one interval.
 
     ``kind`` buckets the summarize table: ``host_io`` (chunk reads/writes),
-    ``device`` (batched device dispatch), ``collective`` (mesh programs in
-    parallel/), ``task``/``dispatch``/``run`` (structural), ``barrier``
+    ``host_compute`` (a blocking device dispatch, timed on the host clock
+    from launch to the results on the host), ``collective`` (mesh programs
+    in parallel/), ``task``/``dispatch``/``run`` (structural), ``barrier``
     (peer waits), ``host`` (everything else), ``timing`` (retroactive
     record_timing bridge events — excluded from bucket sums).  Pass
     ``task=<identifier>`` when the span may open in a worker thread, where
-    the per-thread parent stack cannot see the task span.
+    the per-thread parent stack cannot see the task span.  An enabled span
+    also enters a ``jax.profiler.TraceAnnotation`` of its name, so a
+    profiler trace shows it beside the device's operations.
     """
     st = _RUN
     if st is None:

@@ -364,7 +364,8 @@ def connected_components_raw(
     vigra.labelMultiArrayWithBackground on a segmentation (used to re-close
     labels after halo cropping, reference watershed.py:329-333).
     """
-    return _flat_cc(mask, connectivity, partition, per_slice)[0]
+    with jax.named_scope("cc.flat"):
+        return _flat_cc(mask, connectivity, partition, per_slice)[0]
 
 
 @partial(jax.jit, static_argnames=("connectivity", "per_slice"))
@@ -377,7 +378,8 @@ def connected_components_raw_with_iters(
     """``connected_components_raw`` plus its fixpoint round count — the
     bench/CI instrumentation hook for the flat-vs-coarse iteration contract
     (tools/ci_check.sh asserts coarse < flat on the serpentine fixture)."""
-    return _flat_cc(mask, connectivity, partition, per_slice)
+    with jax.named_scope("cc.flat"):
+        return _flat_cc(mask, connectivity, partition, per_slice)
 
 
 # ---------------------------------------------------------------------------
@@ -580,7 +582,37 @@ def _coarse_cc_core(
     ``labels[p]`` is the minimal id of p's component (``sentinel`` on
     background) and ``stats`` carries int32 scalars ``fixpoint_iters``
     (tile-fixpoint rounds), ``live_tile_rounds`` (Σ live tiles per round)
-    and ``merge_pairs`` (valid tile-face equivalences)."""
+    and ``merge_pairs`` (valid tile-face equivalences).  Its two stages run
+    under the named scopes ``cc.tiles`` and ``cc.merge``, which a device
+    trace shows in each operation's name."""
+    with jax.named_scope("cc.tiles"):
+        L, stats = _tile_fixpoints(
+            mask, ids, sentinel, connectivity, partition, per_slice, tile
+        )
+    with jax.named_scope("cc.merge"):
+        pairs = _tile_boundary_pairs(
+            L,
+            partition,
+            tile,
+            connectivity,
+            per_slice,
+            sentinel,
+        )
+        if pairs is not None:
+            from .unionfind import apply_value_roots, merge_value_table
+
+            a_vals, b_vals, n_valid = pairs
+            vals, root_vals = merge_value_table(a_vals, b_vals)
+            L = apply_value_roots(L, vals, root_vals)
+            stats["merge_pairs"] = n_valid
+    return L, stats
+
+
+def _tile_fixpoints(mask, ids, sentinel, connectivity, partition, per_slice,
+                    tile):
+    """The tile stage of ``_coarse_cc_core``: every tile's min-label
+    fixpoint in tile-local id space, translated to ``ids``; returns
+    ``(labels, stats)`` before the tile-face merge."""
     shape = mask.shape
     ndim = mask.ndim
     grid = _tile_grid(shape, tile)
@@ -677,21 +709,6 @@ def _coarse_cc_core(
         "live_tile_rounds": live_rounds,
         "merge_pairs": jnp.int32(0),
     }
-    pairs = _tile_boundary_pairs(
-        L,
-        partition,
-        tile,
-        connectivity,
-        per_slice,
-        sentinel,
-    )
-    if pairs is not None:
-        from .unionfind import apply_value_roots, merge_value_table
-
-        a_vals, b_vals, n_valid = pairs
-        vals, root_vals = merge_value_table(a_vals, b_vals)
-        L = apply_value_roots(L, vals, root_vals)
-        stats["merge_pairs"] = n_valid
     return L, stats
 
 
@@ -710,11 +727,13 @@ def connected_components_coarse_raw(
     shape = mask.shape
     tile = resolve_coarse_tile(shape, tile)
     size = int(np.prod(shape))
-    ids = jnp.arange(size, dtype=jnp.int32).reshape(shape)
+    with jax.named_scope("cc.tiles"):
+        ids = jnp.arange(size, dtype=jnp.int32).reshape(shape)
     lab, stats = _coarse_cc_core(
         mask, ids, size, connectivity, partition, per_slice, tile
     )
-    return jnp.where(mask, lab, jnp.int32(-1)), stats
+    with jax.named_scope("cc.merge"):
+        return jnp.where(mask, lab, jnp.int32(-1)), stats
 
 
 def connected_components_coarse(
@@ -807,7 +826,8 @@ def merge_slice_labels(
 
 
 @partial(
-    jax.jit, static_argnames=("connectivity", "per_slice", "coarse_tile")
+    jax.jit,
+    static_argnames=("connectivity", "per_slice", "coarse_tile", "with_rounds"),
 )
 def connected_components(
     mask: jnp.ndarray,
@@ -815,12 +835,17 @@ def connected_components(
     partition: Optional[jnp.ndarray] = None,
     per_slice: bool = False,
     coarse_tile: Optional[Tuple[int, ...]] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    with_rounds: bool = False,
+):
     """Consecutive component labeling: background 0, components 1..n.
 
     Returns ``(labels, n_components)``.  Consecutive ids come from ranking the
     component roots (minimal flat indices) with a cumsum — no dynamic shapes.
     See ``connected_components_raw`` for ``partition`` / ``per_slice``.
+    ``with_rounds`` appends the int32 round count of the fixpoint loop
+    (the coarse kernel's ``fixpoint_iters``, the flat kernel's rounds;
+    None on the Pallas paths, which count none) as a third output of the
+    same program.
 
     Mode switches (read at trace time, ops/_backend.py):
       * ``CTT_CC_MODE=coarse`` — the coarse-to-fine tiled kernel
@@ -845,6 +870,9 @@ def connected_components(
     """
     from . import _backend
 
+    def out(labels, n, rounds):
+        return (labels, n, rounds) if with_rounds else (labels, n)
+
     if partition is None:
         from .pallas_cc import (
             pallas_cc_available,
@@ -855,31 +883,34 @@ def connected_components(
         )
 
         if pallas_cc_available(mask.shape, connectivity, per_slice):
-            return pallas_connected_components(mask)
+            return out(*pallas_connected_components(mask), None)
         if pallas_cc_tiled_available(mask.shape, connectivity, per_slice):
-            return pallas_connected_components_tiled(
+            return out(*pallas_connected_components_tiled(
                 mask, pallas_cc_tile(mask.shape)
-            )
+            ), None)
         if (
             _backend.use_slices_cc()
             and not per_slice and mask.ndim == 3 and connectivity == 1
         ):
-            sliced = connected_components_raw(
+            sliced, rounds = connected_components_raw_with_iters(
                 mask, connectivity, None, per_slice=True
             )
-            return merge_slice_labels(mask, sliced)
+            return out(*merge_slice_labels(mask, sliced), rounds)
     size = int(np.prod(mask.shape))
     if _backend.use_coarse_cc() or coarse_tile is not None:
         tile = resolve_coarse_tile(mask.shape, coarse_tile)
-        raw, _ = connected_components_coarse_raw(
+        raw, stats = connected_components_coarse_raw(
             mask, connectivity, partition, per_slice, tile
         )
+        rounds = stats["fixpoint_iters"]
     else:
-        raw = connected_components_raw(
+        raw, rounds = connected_components_raw_with_iters(
             mask, connectivity, partition, per_slice
         )
-    labels, n = consecutive_from_flat_roots(raw.reshape(-1), size)
-    return labels.reshape(mask.shape), n
+    with jax.named_scope("cc.rank"):
+        labels, n = consecutive_from_flat_roots(raw.reshape(-1), size)
+        labels = labels.reshape(mask.shape)
+    return out(labels, n, rounds)
 
 
 def rank_of_flat_roots(flat: jnp.ndarray, size: int):
@@ -910,12 +941,14 @@ def connected_components_labels(
     connectivity: int = 1,
     per_slice: bool = False,
     coarse_tile: Optional[Tuple[int, ...]] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    with_rounds: bool = False,
+):
     """Split a label image into its connected pieces (CC within equal labels,
-    background 0) — vigra.labelMultiArrayWithBackground equivalent."""
+    background 0) — vigra.labelMultiArrayWithBackground equivalent;
+    ``with_rounds`` as in ``connected_components``."""
     return connected_components(
         labels > 0, connectivity, partition=labels, per_slice=per_slice,
-        coarse_tile=coarse_tile,
+        coarse_tile=coarse_tile, with_rounds=with_rounds,
     )
 
 

@@ -410,17 +410,17 @@ def _flood_scan_impl(
     return jnp.where(mask, label, 0), alt, stats
 
 
-@partial(jax.jit, static_argnames=("max_iter", "per_slice", "tile"))
+@partial(jax.jit, static_argnames=("max_iter", "per_slice"))
 def _seeded_watershed_scan(
     hmap: jnp.ndarray,
     seeds: jnp.ndarray,
     mask: jnp.ndarray,
     max_iter: int = 0,
     per_slice: bool = False,
-    tile: Optional[Tuple[int, ...]] = None,
 ) -> jnp.ndarray:
-    """Flood labels of ``_flood_scan_impl`` (the documented kernel)."""
-    return _flood_scan_impl(hmap, seeds, mask, max_iter, per_slice, tile)[0]
+    """Flood labels of ``_flood_scan_impl`` (the documented kernel), with
+    no tile warm start."""
+    return _flood_scan_impl(hmap, seeds, mask, max_iter, per_slice, None)[0]
 
 
 @partial(jax.jit, static_argnames=("per_slice", "tile"))
@@ -541,9 +541,22 @@ def seeded_watershed_hier(
     return labels, table, stats
 
 
+def _flood_rounds(stats, tiled: bool):
+    """A flood's round counts for ``with_rounds`` callers, from
+    ``_flood_scan_impl``'s stats: the global altitude and assignment loops
+    (``flood``) and, where the flood was warm-started from tiles, the tile
+    loops (``flood_tile``)."""
+    rounds = {"flood": stats["flood_alt_iters"] + stats["flood_assign_iters"]}
+    if tiled:
+        rounds["flood_tile"] = stats["flood_tile_iters"]
+    return rounds
+
+
 @partial(
     jax.jit,
-    static_argnames=("connectivity", "max_iter", "per_slice", "coarse_tile"),
+    static_argnames=(
+        "connectivity", "max_iter", "per_slice", "coarse_tile", "with_rounds",
+    ),
 )
 def seeded_watershed(
     hmap: jnp.ndarray,
@@ -553,7 +566,8 @@ def seeded_watershed(
     max_iter: int = 0,
     per_slice: bool = False,
     coarse_tile: Optional[Tuple[int, ...]] = None,
-) -> jnp.ndarray:
+    with_rounds: bool = False,
+):
     """Flood ``seeds`` (int32, 0 = unlabeled) over height map ``hmap``.
 
     Voxels outside ``mask`` stay 0 and do not conduct floods.  ``max_iter=0``
@@ -563,11 +577,15 @@ def seeded_watershed(
     from tile-local fixpoints — identical labels, fewer global rounds (see
     ``_flood_scan_impl``); only the fixpoint scan path tiles (``max_iter``
     caps count global rounds, so a warm start would change their meaning).
+    ``with_rounds`` returns ``(labels, rounds)``: the int32 round counts of
+    ``_flood_rounds``, outputs of the same program (empty on the Pallas
+    whole-slice, capped and neighbor-sweep paths, which count none).
     """
     if mask is None:
         mask_arr = jnp.ones(hmap.shape, dtype=bool)
     else:
         mask_arr = mask.astype(bool)
+    rounds = {}
     if connectivity == 1:
         tile = resolve_flood_tile(hmap.shape, coarse_tile)
         if max_iter == 0:
@@ -580,25 +598,31 @@ def seeded_watershed(
 
             if pallas_flood_available(hmap.shape, per_slice):
                 # whole-slice flood in VMEM (opt-in, CTT_FLOOD_MODE=pallas)
-                return flood_slices(hmap, seeds, mask_arr)
-            if tile is not None and pallas_flood_tiled_available(
+                labels = flood_slices(hmap, seeds, mask_arr)
+            elif tile is not None and pallas_flood_tiled_available(
                 hmap.shape, per_slice, tile
             ):
                 # tile-local altitude fixpoints in VMEM as the phase-1 warm
                 # state; the XLA loops finish the cross-tile structure
                 warm = flood_tiles_warm(hmap, seeds, mask_arr, tile[1:])
-                return _flood_scan_impl(
+                labels, _, stats = _flood_scan_impl(
                     hmap, seeds, mask_arr, 0, per_slice, tile, warm=warm
-                )[0]
-            return _seeded_watershed_scan(
-                hmap, seeds, mask_arr, per_slice=per_slice, tile=tile
+                )
+                rounds = _flood_rounds(stats, tiled=True)
+            else:
+                labels, _, stats = flood_with_stats(
+                    hmap, seeds, mask_arr, per_slice=per_slice, tile=tile
+                )
+                rounds = _flood_rounds(stats, tiled=tile is not None)
+        else:
+            labels = _seeded_watershed_scan(
+                hmap, seeds, mask_arr, max_iter=max_iter, per_slice=per_slice
             )
-        return _seeded_watershed_scan(
-            hmap, seeds, mask_arr, max_iter=max_iter, per_slice=per_slice
+    else:
+        labels = _seeded_watershed_sweep(
+            hmap, seeds, mask_arr, connectivity, max_iter, per_slice
         )
-    return _seeded_watershed_sweep(
-        hmap, seeds, mask_arr, connectivity, max_iter, per_slice
-    )
+    return (labels, rounds) if with_rounds else labels
 
 
 @partial(jax.jit, static_argnames=("connectivity", "max_iter", "per_slice"))
@@ -717,14 +741,18 @@ def suppress_seeds(
     return maxima & (cover <= d2 * (1.0 + 1e-5) + 1e-5)
 
 
-@partial(jax.jit, static_argnames=("sigma", "per_slice", "nms", "pixel_pitch"))
+@partial(
+    jax.jit,
+    static_argnames=("sigma", "per_slice", "nms", "pixel_pitch", "with_rounds"),
+)
 def dt_seeds(
     dt: jnp.ndarray,
     sigma: float = 2.0,
     per_slice: bool = False,
     nms: bool = False,
     pixel_pitch: Optional[Tuple[float, ...]] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    with_rounds: bool = False,
+):
     """Seeds from a distance transform: smooth → local maxima (plateaus merged by
     full-connectivity CC over the maxima mask) → consecutive labels.
 
@@ -733,6 +761,7 @@ def dt_seeds(
     and labels seeds within each z-slice independently (2d seed mode).
     ``nms`` additionally suppresses maxima dominated by stronger nearby maxima
     (reference ``non_maximum_suppression`` config knob, watershed.py:182-204).
+    ``with_rounds`` appends the seed CC's int32 round count.
     """
     if sigma and sigma > 0:
         # per-slice mode smooths within slices only (reference 2d seed path)
@@ -746,10 +775,10 @@ def dt_seeds(
         local_max = suppress_seeds(
             local_max, dt, per_slice=per_slice, pixel_pitch=pixel_pitch
         )
-    seeds, n = connected_components(
-        local_max, connectivity=dt.ndim, per_slice=per_slice
+    return connected_components(
+        local_max, connectivity=dt.ndim, per_slice=per_slice,
+        with_rounds=with_rounds,
     )
-    return seeds, n
 
 
 @partial(
@@ -765,6 +794,7 @@ def dt_seeds(
         "size_filter",
         "invert_input",
         "non_maximum_suppression",
+        "with_rounds",
     ),
 )
 def dt_watershed(
@@ -781,7 +811,8 @@ def dt_watershed(
     invert_input: bool = False,
     non_maximum_suppression: bool = False,
     valid: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    with_rounds: bool = False,
+):
     """The full per-block DT-watershed — one fused XLA program.
 
     threshold → distance transform (2d or 3d) → smoothed-maxima seeds
@@ -796,6 +827,14 @@ def dt_watershed(
     size filter are restricted to ``valid``: labels never occupy padding, so
     segment voxel counts match the clipped computation — replicated copies of
     a small border fragment must not carry it over ``size_filter``.
+
+    Each phase runs under a named scope that a device trace shows in its
+    operations' names: ``ws.dt`` (threshold, distance transform),
+    ``ws.seeds``, ``ws.hmap``, ``ws.flood`` and ``ws.size_filter``.
+    ``with_rounds`` appends the phases' int32 round counts as outputs of the
+    same program: ``{"flood": [...], "flood_tile": [...], "cc": [...]}``, one
+    entry per flood (the flood, the size filter's re-flood) whose path
+    counts its rounds, and the seed CC's (None where it counts none).
     """
     from .dt import _distance_transform, distance_transform_2d_stack
 
@@ -804,35 +843,53 @@ def dt_watershed(
         # pitch only applies to the 3d distance transform
         raise ValueError("pixel_pitch requires apply_dt_2d=False")
 
-    x = input_.astype(jnp.float32)
-    if invert_input:
-        x = 1.0 - x
-    fg = x < threshold
-    if mask is not None:
-        fg = fg & mask.astype(bool)
+    with jax.named_scope("ws.dt"):
+        x = input_.astype(jnp.float32)
+        if invert_input:
+            x = 1.0 - x
+        fg = x < threshold
+        if mask is not None:
+            fg = fg & mask.astype(bool)
 
-    if apply_dt_2d and x.ndim == 3:
-        dt = distance_transform_2d_stack(fg, pixel_pitch=None)
-    else:
-        dt = _distance_transform(fg, pixel_pitch)
+        if apply_dt_2d and x.ndim == 3:
+            dt = distance_transform_2d_stack(fg, pixel_pitch=None)
+        else:
+            dt = _distance_transform(fg, pixel_pitch)
 
     per_slice_seeds = apply_ws_2d and x.ndim == 3
-    seeds, n_seeds = dt_seeds(
-        dt, sigma_seeds, per_slice=per_slice_seeds,
-        nms=non_maximum_suppression, pixel_pitch=pixel_pitch,
-    )
-    hmap = make_hmap(x, dt, alpha, sigma_weights, per_slice=per_slice_seeds)
-    flood_mask = fg if valid is None else fg & valid.astype(bool)
-    labels = seeded_watershed(
-        hmap, seeds, mask=flood_mask, per_slice=per_slice_seeds
-    )
+    with jax.named_scope("ws.seeds"):
+        seeds, n_seeds, seed_rounds = dt_seeds(
+            dt, sigma_seeds, per_slice=per_slice_seeds,
+            nms=non_maximum_suppression, pixel_pitch=pixel_pitch,
+            with_rounds=True,
+        )
+    with jax.named_scope("ws.hmap"):
+        hmap = make_hmap(
+            x, dt, alpha, sigma_weights, per_slice=per_slice_seeds
+        )
+    with jax.named_scope("ws.flood"):
+        flood_mask = fg if valid is None else fg & valid.astype(bool)
+        labels, flood = seeded_watershed(
+            hmap, seeds, mask=flood_mask, per_slice=per_slice_seeds,
+            with_rounds=True,
+        )
+    floods = [flood]
     if size_filter > 0:
         num_segments = int(np.prod(x.shape)) // 2 + 2
-        labels = apply_size_filter(
-            labels, hmap, size_filter, num_segments, mask=flood_mask,
-            per_slice=per_slice_seeds,
-        )
-    return labels, n_seeds
+        with jax.named_scope("ws.size_filter"):
+            labels, reflood = apply_size_filter(
+                labels, hmap, size_filter, num_segments, mask=flood_mask,
+                per_slice=per_slice_seeds, with_rounds=True,
+            )
+        floods.append(reflood)
+    if not with_rounds:
+        return labels, n_seeds
+    rounds = {
+        key: [f[key] for f in floods if key in f]
+        for key in ("flood", "flood_tile")
+    }
+    rounds["cc"] = [seed_rounds]
+    return labels, n_seeds, rounds
 
 
 @partial(
@@ -963,7 +1020,10 @@ def make_hmap(
 
 @partial(
     jax.jit,
-    static_argnames=("size_filter", "num_segments", "connectivity", "per_slice"),
+    static_argnames=(
+        "size_filter", "num_segments", "connectivity", "per_slice",
+        "with_rounds",
+    ),
 )
 def apply_size_filter(
     labels: jnp.ndarray,
@@ -974,7 +1034,8 @@ def apply_size_filter(
     connectivity: int = 1,
     per_slice: bool = False,
     protect_upto: Optional[jnp.ndarray] = None,
-) -> jnp.ndarray:
+    with_rounds: bool = False,
+):
     """Remove segments smaller than ``size_filter`` voxels and re-flood the freed
     voxels from the surviving segments (reference ``_apply_watershed``
     size-filter step, watershed.py:242-250).
@@ -982,14 +1043,17 @@ def apply_size_filter(
     ``num_segments`` is the *exclusive* upper bound on label values, i.e.
     max_label + 1 (pass ``n + 1`` for labels 1..n from dt_seeds).
     ``protect_upto`` (traced scalar) exempts labels ≤ it from the filter
-    (the reference ``exclude=`` seam for two-pass continuation labels)."""
+    (the reference ``exclude=`` seam for two-pass continuation labels).
+    ``with_rounds`` returns ``(labels, rounds)`` of the re-flood, as
+    ``seeded_watershed`` does."""
     counts = jnp.bincount(labels.reshape(-1), length=num_segments)
     too_small = counts[labels] < size_filter
     if protect_upto is not None:
         too_small = too_small & (labels > protect_upto)
     kept = jnp.where(too_small, 0, labels)
     return seeded_watershed(
-        hmap, kept, mask=mask, connectivity=connectivity, per_slice=per_slice
+        hmap, kept, mask=mask, connectivity=connectivity, per_slice=per_slice,
+        with_rounds=with_rounds,
     )
 
 

@@ -89,7 +89,7 @@ def stacked_dispatch(task, compute_fn, payload, blocking, config,
 
     faults.check("executor.stage_compute", id=all_ids[0])
     with obs_trace.span(
-        "stage_compute", kind="device", task=task.identifier,
+        "stage_compute", kind="host_compute", task=task.identifier,
         blocks=len(all_ids), block_ids=list(all_ids),
     ), hbm.use_guard():
         result = compute_fn(payload, blocking, config)
@@ -97,6 +97,22 @@ def stacked_dispatch(task, compute_fn, payload, blocking, config,
     if fused:
         obs_metrics.inc("device.fused_blocks", len(all_ids))
     return result
+
+
+def run_split_batch(task, block_ids, blocking, config) -> None:
+    """One batch of a split-protocol task through its three stages in turn
+    on the calling thread — the monolithic ``process_block_batch`` of the
+    tasks that implement ``read_batch`` / ``compute_batch`` /
+    ``write_batch``.  Each stage runs in a child span of the caller's
+    (``block_batch`` or ``block:<task>``), so a trace tells the batch's
+    host IO from its device compute."""
+    with obs_trace.span("batch_read", kind="host_io", task=task.identifier):
+        payload = task.read_batch(block_ids, blocking, config)
+    with obs_trace.span("batch_compute", kind="host_compute",
+                        task=task.identifier):
+        result = task.compute_batch(payload, blocking, config)
+    with obs_trace.span("batch_write", kind="host_io", task=task.identifier):
+        task.write_batch(result, blocking, config)
 
 
 def profiler_trace(config: Dict[str, Any]):
@@ -198,7 +214,8 @@ class LocalExecutor(BaseExecutor):
                 # opens in a worker thread where the per-thread parent
                 # stack cannot see the enclosing task span
                 with obs_trace.span(
-                    "block", kind="host", task=task.identifier, block=bid
+                    f"block:{task.identifier}", kind="host",
+                    task=task.identifier, block=bid,
                 ):
                     task.process_block(bid, blocking, config)
                 durations.append(time.perf_counter() - t0)
@@ -378,7 +395,7 @@ class TpuExecutor(BaseExecutor):
                 # (a concurrent serve job's eviction must not free buffers
                 # an in-flight batch still reads — runtime/hbm.py)
                 with obs_trace.span(
-                    "block_batch", kind="device", task=task.identifier,
+                    "block_batch", kind="host", task=task.identifier,
                     blocks=len(chunk), block_ids=list(chunk),
                 ), hbm.use_guard():
                     batch_fn(chunk, blocking, config)

@@ -399,7 +399,7 @@ class _ChainRunner:
                 )
             t1 = time.perf_counter()
             with obs_trace.span(
-                "stage_compute", kind="device", task=mid,
+                "stage_compute", kind="host_compute", task=mid,
                 chain=plan.chain.name, blocks=len(chunk),
                 block_ids=list(chunk),
             ):

@@ -7,6 +7,7 @@ from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
+from ..obs import metrics as obs_metrics
 from ..runtime.task import BlockTask, SimpleTask
 from ..utils import store
 from ..utils.blocking import Blocking
@@ -186,6 +187,26 @@ def read_threads(config) -> int:
     return max(
         int(config.get("read_threads", DEFAULT_TASK_CONFIG["read_threads"])), 1
     )
+
+
+def count_block_rounds(rounds: Dict[str, Any], n_blocks: int) -> None:
+    """Add a block program's round counts to the obs counters.
+
+    ``rounds`` maps ``flood`` / ``flood_tile`` / ``cc`` to lists of per-block
+    int32 arrays fetched with the program's labels (one entry per flood or
+    CC of the program, None for a CC path that counts none;
+    ``ops.watershed.dt_watershed(with_rounds=True)``); only the first
+    ``n_blocks`` (the real, unpadded blocks) count.  The readers divide by
+    ``blocks.computed``: rounds per block."""
+
+    def total(key):
+        return int(sum(np.asarray(r)[:n_blocks].sum()
+                       for r in rounds.get(key, ()) if r is not None))
+
+    obs_metrics.inc("blocks.computed", n_blocks)
+    obs_metrics.inc("flood.rounds", total("flood"))
+    obs_metrics.inc("flood.tile_rounds", total("flood_tile"))
+    obs_metrics.inc("cc.rounds", total("cc"))
 
 
 def resolve_n_blocks(

@@ -28,6 +28,7 @@ import numpy as np
 from ..ops import events as events_ops
 from ..parallel.dispatch import read_block_batch, write_block_batch
 from ..runtime import hbm
+from ..runtime.executor import run_split_batch
 from ..utils import store
 from ..utils.blocking import Blocking
 from .base import VolumeTask, read_threads
@@ -143,13 +144,8 @@ class EventBuildingTask(VolumeTask):
             table[:, 0] += bh.inner.begin[0]  # local -> global frame index
             ev_ds.write_chunk((batch.block_ids[i],), table)
 
-    def _run_batch(self, block_ids: List[int], blocking: Blocking, config):
-        self.write_batch(
-            self.compute_batch(
-                self.read_batch(block_ids, blocking, config), blocking, config
-            ),
-            blocking, config,
-        )
+    def _run_batch(self, block_ids, blocking, config):
+        run_split_batch(self, block_ids, blocking, config)
 
     def process_block(self, block_id, blocking, config):
         self._run_batch([block_id], blocking, config)

@@ -51,6 +51,7 @@ from ..ops import hier as hier_ops
 from ..ops import watershed as ws_ops
 from ..parallel.dispatch import BlockBatch, read_block_batch, write_block_batch
 from ..runtime import hbm
+from ..runtime.executor import run_split_batch
 from ..utils import store
 from ..utils.blocking import Blocking
 from .base import (
@@ -250,13 +251,8 @@ class HierarchyBlocksTask(VolumeTask):
             sad_ds.write_chunk((bid,), saddles)
             obs_metrics.inc("hier.tables_built")
 
-    def _run_batch(self, block_ids: List[int], blocking: Blocking, config):
-        self.write_batch(
-            self.compute_batch(
-                self.read_batch(block_ids, blocking, config), blocking, config
-            ),
-            blocking, config,
-        )
+    def _run_batch(self, block_ids, blocking, config):
+        run_split_batch(self, block_ids, blocking, config)
 
     def process_block(self, block_id, blocking, config):
         self._run_batch([block_id], blocking, config)
@@ -729,13 +725,8 @@ class ResegmentTask(VolumeTask):
             n_threads=read_threads(config),
         )
 
-    def _run_batch(self, block_ids: List[int], blocking: Blocking, config):
-        self.write_batch(
-            self.compute_batch(
-                self.read_batch(block_ids, blocking, config), blocking, config
-            ),
-            blocking, config,
-        )
+    def _run_batch(self, block_ids, blocking, config):
+        run_split_batch(self, block_ids, blocking, config)
 
     def process_block(self, block_id, blocking, config):
         self._run_batch([block_id], blocking, config)

@@ -26,6 +26,7 @@ import numpy as np
 from ..ops.filters import minimum_filter
 from ..parallel.dispatch import read_block_batch, write_block_batch
 from ..runtime import hbm
+from ..runtime.executor import run_split_batch
 from ..utils import store
 from ..utils.blocking import Blocking
 from .base import VolumeSimpleTask, VolumeTask, read_threads
@@ -170,13 +171,8 @@ class MinfilterTask(VolumeTask):
             n_threads=read_threads(config),
         )
 
-    def _run_batch(self, block_ids, blocking: Blocking, config):
-        self.write_batch(
-            self.compute_batch(
-                self.read_batch(block_ids, blocking, config), blocking, config
-            ),
-            blocking, config,
-        )
+    def _run_batch(self, block_ids, blocking, config):
+        run_split_batch(self, block_ids, blocking, config)
 
     def process_block(self, block_id, blocking, config):
         self._run_batch([block_id], blocking, config)

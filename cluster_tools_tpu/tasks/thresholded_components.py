@@ -28,8 +28,12 @@ from ..ops import filters
 from ..ops.unionfind import merge_assignments_device, merge_assignments_np
 from ..parallel.dispatch import read_block_batch, write_block_batch
 from ..runtime import hbm
+from ..runtime.executor import run_split_batch
 from ..utils.blocking import Blocking
-from .base import VolumeSimpleTask, VolumeTask, merge_threads, read_ragged_chunks, read_threads
+from .base import (
+    VolumeSimpleTask, VolumeTask, count_block_rounds, merge_threads,
+    read_ragged_chunks, read_threads,
+)
 
 MAX_IDS_KEY = "thresholded_components/max_ids"
 FACES_KEY = "thresholded_components/faces"
@@ -42,21 +46,25 @@ ASSIGNMENTS_NAME = "thresholded_components_assignments.npy"
 )
 def _components_batch(batch, threshold, mode, sigma, connectivity,
                       coarse_tile=None):
-    x = batch
-    if sigma:
-        x = jax.vmap(lambda b: filters.gaussian(b, sigma))(x)
-    if mode == "greater":
-        mask = x > threshold
-    elif mode == "less":
-        mask = x < threshold
-    else:
-        mask = x == threshold
-    labels, n = jax.vmap(
+    """The block components program: ``(labels, n, rounds)`` per block of
+    the batch, ``rounds`` the CC's int32 round counts
+    (``connected_components(with_rounds=True)``).  The threshold runs under
+    the named scope ``cc.threshold``, the CC under those of ``ops/cc.py``."""
+    with jax.named_scope("cc.threshold"):
+        x = batch
+        if sigma:
+            x = jax.vmap(lambda b: filters.gaussian(b, sigma))(x)
+        if mode == "greater":
+            mask = x > threshold
+        elif mode == "less":
+            mask = x < threshold
+        else:
+            mask = x == threshold
+    return jax.vmap(
         lambda m: cc_ops.connected_components(
-            m, connectivity, coarse_tile=coarse_tile
+            m, connectivity, coarse_tile=coarse_tile, with_rounds=True
         )
     )(mask)
-    return labels, n
 
 
 class BlockComponentsTask(VolumeTask):
@@ -263,7 +271,7 @@ class BlockComponentsTask(VolumeTask):
         coarse_tile = config.get("coarse_tile", None)
         if coarse_tile is not None and not isinstance(coarse_tile, int):
             coarse_tile = tuple(coarse_tile)
-        labels, _ = _components_batch(
+        labels, _, rounds = _components_batch(
             db.arrays[0],
             float(config.get("threshold", 0.5)),
             config.get("threshold_mode", "greater"),
@@ -271,7 +279,10 @@ class BlockComponentsTask(VolumeTask):
             int(config.get("connectivity", 1)),
             coarse_tile,
         )
-        labels = np.array(labels[:n])  # writable host copy (mask edit below)
+        # one device-to-host copy for the labels and the round counts
+        labels, rounds = jax.device_get((labels[:n], rounds))
+        count_block_rounds({"cc": [rounds]}, n)
+        labels = np.array(labels)  # writable host copy (mask edit below)
         if masks is not None:
             for i, m in enumerate(masks):
                 sl = tuple(slice(0, s) for s in m.shape)
@@ -290,13 +301,8 @@ class BlockComponentsTask(VolumeTask):
             inner = labels[i][bh.inner_local.slicing]
             max_ids.write_chunk((bid,), np.array([inner.max()], dtype=np.int64))
 
-    def _run_batch(self, block_ids: List[int], blocking: Blocking, config):
-        self.write_batch(
-            self.compute_batch(
-                self.read_batch(block_ids, blocking, config), blocking, config
-            ),
-            blocking, config,
-        )
+    def _run_batch(self, block_ids, blocking, config):
+        run_split_batch(self, block_ids, blocking, config)
 
     def process_block(self, block_id, blocking, config):
         self._run_batch([block_id], blocking, config)

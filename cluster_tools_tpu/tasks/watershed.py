@@ -24,9 +24,12 @@ import numpy as np
 from ..ops import watershed as ws_ops
 from ..ops.cc import connected_components_labels
 from ..parallel.mesh import put_sharded
+from ..runtime.executor import run_split_batch
 from ..utils import store
 from ..utils.blocking import Blocking, make_checkerboard_block_lists
-from .base import VolumeSimpleTask, VolumeTask, read_threads
+from .base import (
+    VolumeSimpleTask, VolumeTask, count_block_rounds, read_threads,
+)
 
 MAX_IDS_KEY = "watershed/max_ids"
 
@@ -36,7 +39,10 @@ def _fused_ws_kernel(params_key, block_shape, with_mask: bool, crop_cc: bool,
                      coarse_tile=None):
     """One jitted program per config: flood → per-block dynamic-slice crop to
     the inner box → CC re-close (reference watershed.py:329-333), vmapped
-    over the stacked block batch.
+    over the stacked block batch.  Returns ``(labels, rounds)``: the
+    per-block int32 round counts of ``dt_watershed(with_rounds=True)``, the
+    re-close's CC rounds among ``rounds["cc"]`` (see ``count_block_rounds``).
+    The re-close runs under the named scope ``ws.reclose``.
 
     Fusing the crop+CC into the flood dispatch removes two host↔device
     round-trips of the full batch per stage and runs the CC on the cropped
@@ -51,16 +57,23 @@ def _fused_ws_kernel(params_key, block_shape, with_mask: bool, crop_cc: bool,
 
     def one(x, v, start, m):
         if with_mask:
-            lab, _ = kernel(x, mask=m, valid=v)
+            lab, _, rounds = kernel(x, mask=m, valid=v, with_rounds=True)
         else:
-            lab, _ = kernel(x, valid=v)
+            lab, _, rounds = kernel(x, valid=v, with_rounds=True)
         if crop_cc:
-            lab = lax.dynamic_slice(lab, (start[0], start[1], start[2]), bs)
-            # re-close through the ctt-cc kernel: the same
-            # connected_components() dispatch as every other CC call site
-            # (coarse_tile config knob > CTT_CC_TILE pin > backend default)
-            lab, _ = connected_components_labels(lab, coarse_tile=coarse_tile)
-        return lab
+            with jax.named_scope("ws.reclose"):
+                lab = lax.dynamic_slice(
+                    lab, (start[0], start[1], start[2]), bs
+                )
+                # re-close through the ctt-cc kernel: the same
+                # connected_components() dispatch as every other CC call
+                # site (coarse_tile config knob > CTT_CC_TILE pin > backend
+                # default)
+                lab, _, cc_rounds = connected_components_labels(
+                    lab, coarse_tile=coarse_tile, with_rounds=True
+                )
+            rounds = dict(rounds, cc=rounds["cc"] + [cc_rounds])
+        return lab, rounds
 
     if with_mask:
         return jax.jit(jax.vmap(one))
@@ -320,11 +333,14 @@ class WatershedTask(VolumeTask):
         xb, vb, sb = db.arrays
         n_real = db.n
         if mask is None:
-            labels = fused(xb, vb, sb)
+            labels, rounds = fused(xb, vb, sb)
         else:
             mb, _ = put_sharded(mask, config)
-            labels = fused(xb, vb, sb, mb)
-        return batch, np.asarray(labels)[:n_real].astype(np.uint64)
+            labels, rounds = fused(xb, vb, sb, mb)
+        # one device-to-host copy for the labels and the round counts
+        labels, rounds = jax.device_get((labels, rounds))
+        count_block_rounds(rounds, n_real)
+        return batch, labels[:n_real].astype(np.uint64)
 
     def write_batch(self, result, blocking: Blocking, config):
         """Stage 3: apply block-id offsets, record per-block max ids, write
@@ -349,13 +365,8 @@ class WatershedTask(VolumeTask):
             max_ids.write_chunk((bid,), np.array([lab.max()], dtype=np.int64))
             out_ds[bh.inner.slicing] = lab
 
-    def _run_batch(self, block_ids: List[int], blocking: Blocking, config):
-        self.write_batch(
-            self.compute_batch(
-                self.read_batch(block_ids, blocking, config), blocking, config
-            ),
-            blocking, config,
-        )
+    def _run_batch(self, block_ids, blocking, config):
+        run_split_batch(self, block_ids, blocking, config)
 
     def process_block(self, block_id, blocking, config):
         self._run_batch([block_id], blocking, config)

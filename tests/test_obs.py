@@ -57,7 +57,7 @@ def test_disabled_is_noop_and_allocation_free(tmp_path):
     # values left by an earlier traced test in this worker process
     metrics.reset()
     s1 = trace.span("a", kind="task")
-    s2 = trace.span("b", kind="device", blocks=8)
+    s2 = trace.span("b", kind="host_compute", blocks=8)
     # the disabled path returns ONE shared singleton: no per-call objects,
     # no clock reads, no file IO
     assert s1 is s2
@@ -88,7 +88,7 @@ def test_span_nesting_buckets_and_chrome_export(traced):
         with trace.span("dispatch", kind="dispatch", task="mytask"):
             with trace.span("read", kind="host_io"):
                 pass
-            with trace.span("batch", kind="device"):
+            with trace.span("batch", kind="host_compute"):
                 with trace.span("read2", kind="host_io"):
                     pass
     trace.flush()
@@ -98,15 +98,16 @@ def test_span_nesting_buckets_and_chrome_export(traced):
     assert s["n_task_spans"] == 1
     row = s["tasks"]["mytask"]
     # distinct buckets exist and nested host_io is not double-counted
-    # into device (self-time accounting)
-    for col in ("wall_s", "host_io_s", "device_s", "collective_s", "host_s"):
+    # into host_compute (self-time accounting)
+    for col in ("wall_s", "host_io_s", "host_compute_s", "collective_s",
+                "host_s"):
         assert col in row
     assert row["n_spans"] == 5
-    assert row["wall_s"] >= row["device_s"]
+    assert row["wall_s"] >= row["host_compute_s"]
 
     chrome = to_chrome_trace(run)
     events = chrome["traceEvents"]
-    assert any(e["ph"] == "X" and e["cat"] == "device" for e in events)
+    assert any(e["ph"] == "X" and e["cat"] == "host_compute" for e in events)
     # valid trace_event JSON: every X event carries ts/dur/pid/tid
     for e in events:
         if e["ph"] == "X":

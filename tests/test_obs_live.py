@@ -168,7 +168,7 @@ class TestIncrementalCursor:
         with open(os.path.join(run, "spans.p1.t1.jsonl"), "w") as f:
             f.write(_header() + "\n")
             f.write(_block_span(
-                1, "t", None, 11.0, 2.0, name="block_batch", kind="device",
+                1, "t", None, 11.0, 2.0, name="block_batch", kind="host",
                 block_ids=[0, 1, 2, 3],
             ) + "\n")
         live = LiveRun(run)
@@ -562,7 +562,7 @@ def _write_task_run(run_dir, run_id, tasks, counters=None):
 
 
 _GOLDEN_ROW = {
-    "collective_s": 0.0, "device_s": 0.0, "dispatch_wall_s": 0.0,
+    "collective_s": 0.0, "dispatch_wall_s": 0.0, "host_compute_s": 0.0,
     "host_io_s": 0.0, "host_s": 0.0, "n_spans": 1,
     "overlap_hidden_s": 0.0,
 }
@@ -595,11 +595,11 @@ class TestGoldenJsonOutput:
         assert r.returncode == 0, r.stderr
         assert r.stdout == (
             "run g  (2 task spans, 1 processes)\n"
-            "task      wall_s  host_io_s   device_s  collective_s"
+            "task      wall_s  host_io_s  host_compute_s  collective_s"
             "     host_s  overlap_hidden_s    n_spans\n"
-            "taskB      2.000      0.000      0.000         0.000"
+            "taskB      2.000      0.000           0.000         0.000"
             "      0.000             0.000          1\n"
-            "taskA      1.000      0.000      0.000         0.000"
+            "taskA      1.000      0.000           0.000         0.000"
             "      0.000             0.000          1\n"
             "counters:\n"
             "  store.bytes_read = 10\n"
