@@ -394,16 +394,24 @@ def connected_components_raw_with_iters(
 #   1. labels fixed-size tiles independently — the fixpoint is bounded by the
 #      structure INSIDE one tile, and a per-tile live mask drops converged
 #      tiles (uniform background regions) out after one round;
-#   2. resolves only the tile-face label equivalences with a value-space
-#      union-find whose table is O(tile-boundary area), not O(volume)
-#      (ops.unionfind.merge_value_table);
+#   2. resolves only the tile-face label equivalences with a union-find
+#      whose table is O(tile-boundary area), not O(volume);
 #   3. applies the resolved roots with one gather.
 #
+# Labels are flat indices of the block plus the caller's ``id_offset`` (0
+# for a block, the shard's offset in parallel/sharded.py), so steps 2-3
+# index that dense range directly (ops.unionfind.merge_dense_values): a
+# presence scatter and a prefix count number the face values, and the
+# roots map back through one table of size+1 entries.  The sorted-table
+# merge (merge_value_table, binary searches over every voxel) stays for
+# id spaces the program cannot bound: the hierarchical flood's tables and
+# the sharded CC's all-gathered shard faces.
+#
 # Tile-local labels live in TILE-LOCAL id space during the fixpoint (pointer
-# jumping becomes a per-tile take_along_axis) and translate to the caller's
-# id array afterwards: within one tile, tile-row-major order and any
-# lexicographic global id order are order-isomorphic, so min-label semantics
-# survive the translation exactly.
+# jumping becomes a per-tile take_along_axis) and translate to flat indices
+# afterwards by arithmetic (tile origin plus in-tile coordinates): within
+# one tile, tile-row-major order and block-row-major order are
+# order-isomorphic, so min-label semantics survive the translation exactly.
 
 _TILE_ENV = "CTT_CC_TILE"
 
@@ -566,7 +574,7 @@ def _tile_boundary_pairs(
 
 def _coarse_cc_core(
     mask: jnp.ndarray,
-    ids: jnp.ndarray,
+    id_offset,
     sentinel: int,
     connectivity: int,
     partition: Optional[jnp.ndarray],
@@ -575,19 +583,20 @@ def _coarse_cc_core(
 ):
     """The coarse-to-fine labeling core (traced; see the section comment).
 
-    ``ids`` assigns every voxel its component-id candidate (any array whose
-    row-major order is lexicographic in the voxel coordinates — the local
-    ``arange`` here, the shard-offset global ids in parallel/sharded.py);
-    ``sentinel`` must exceed every id.  Returns ``(labels, stats)`` where
-    ``labels[p]`` is the minimal id of p's component (``sentinel`` on
-    background) and ``stats`` carries int32 scalars ``fixpoint_iters``
-    (tile-fixpoint rounds), ``live_tile_rounds`` (Σ live tiles per round)
-    and ``merge_pairs`` (valid tile-face equivalences).  Its two stages run
-    under the named scopes ``cc.tiles`` and ``cc.merge``, which a device
-    trace shows in each operation's name."""
+    Every voxel's component-id candidate is ``id_offset`` plus its flat
+    index in ``mask`` (offset 0 here, the shard's offset in
+    parallel/sharded.py; an int or a traced scalar); ``sentinel`` must lie
+    outside ``[id_offset, id_offset + mask.size)``.  Returns ``(labels,
+    stats)`` where ``labels[p]`` is the minimal id of p's component
+    (``sentinel`` on background) and ``stats`` carries int32 scalars
+    ``fixpoint_iters`` (tile-fixpoint rounds), ``live_tile_rounds`` (Σ live
+    tiles per round) and ``merge_pairs`` (valid tile-face equivalences).
+    Its two stages run under the named scopes ``cc.tiles`` and
+    ``cc.merge``, which a device trace shows in each operation's name."""
     with jax.named_scope("cc.tiles"):
         L, stats = _tile_fixpoints(
-            mask, ids, sentinel, connectivity, partition, per_slice, tile
+            mask, id_offset, sentinel, connectivity, partition, per_slice,
+            tile,
         )
     with jax.named_scope("cc.merge"):
         pairs = _tile_boundary_pairs(
@@ -599,20 +608,23 @@ def _coarse_cc_core(
             sentinel,
         )
         if pairs is not None:
-            from .unionfind import apply_value_roots, merge_value_table
+            from .unionfind import merge_dense_values
 
             a_vals, b_vals, n_valid = pairs
-            vals, root_vals = merge_value_table(a_vals, b_vals)
-            L = apply_value_roots(L, vals, root_vals)
+            L = merge_dense_values(
+                L, a_vals, b_vals, id_offset, int(np.prod(mask.shape)),
+                sentinel,
+            )
             stats["merge_pairs"] = n_valid
     return L, stats
 
 
-def _tile_fixpoints(mask, ids, sentinel, connectivity, partition, per_slice,
-                    tile):
+def _tile_fixpoints(mask, id_offset, sentinel, connectivity, partition,
+                    per_slice, tile):
     """The tile stage of ``_coarse_cc_core``: every tile's min-label
-    fixpoint in tile-local id space, translated to ``ids``; returns
-    ``(labels, stats)`` before the tile-face merge."""
+    fixpoint in tile-local id space, translated to ``id_offset`` plus the
+    block's flat index; returns ``(labels, stats)`` before the tile-face
+    merge."""
     shape = mask.shape
     ndim = mask.ndim
     grid = _tile_grid(shape, tile)
@@ -695,13 +707,21 @@ def _tile_fixpoints(mask, ids, sentinel, connectivity, partition, per_slice,
         ),
     )
 
-    # translate tile-local labels to the caller's id space (see section
-    # comment: the two orders are isomorphic within a tile, so min survives)
-    sent = jnp.int32(sentinel)
-    gids = tile_stack(ids, tile, 0).reshape(n_tiles, ts)
-    safe = jnp.clip(lab_t.reshape(n_tiles, ts), 0, ts - 1)
-    glab = jnp.take_along_axis(gids, safe, axis=1).reshape(mask_t.shape)
-    glab = jnp.where(lab_t == sent_l, sent, glab)
+    # translate tile-local labels to flat indices of the block (see section
+    # comment: the two orders are isomorphic within a tile, so min survives):
+    # the tile's origin plus the label's in-tile coordinates, row-major over
+    # the block; a label always points at a masked voxel, inside the block
+    strides = np.cumprod((1,) + tuple(shape[:0:-1]))[::-1]
+    origins = np.stack(
+        np.unravel_index(np.arange(n_tiles), grid), axis=1
+    ) * np.asarray(tile)
+    base = jnp.asarray((origins @ strides).astype(np.int32))
+    glab = base.reshape((n_tiles,) + (1,) * ndim) + id_offset
+    rem = lab_t
+    for ax in reversed(range(ndim)):
+        glab = glab + (rem % tile[ax]) * int(strides[ax])
+        rem = rem // tile[ax]
+    glab = jnp.where(lab_t == sent_l, jnp.int32(sentinel), glab)
 
     L = tile_unstack(glab, shape, tile)
     stats = {
@@ -727,10 +747,8 @@ def connected_components_coarse_raw(
     shape = mask.shape
     tile = resolve_coarse_tile(shape, tile)
     size = int(np.prod(shape))
-    with jax.named_scope("cc.tiles"):
-        ids = jnp.arange(size, dtype=jnp.int32).reshape(shape)
     lab, stats = _coarse_cc_core(
-        mask, ids, size, connectivity, partition, per_slice, tile
+        mask, 0, size, connectivity, partition, per_slice, tile
     )
     with jax.named_scope("cc.merge"):
         return jnp.where(mask, lab, jnp.int32(-1)), stats
@@ -772,10 +790,10 @@ def merge_tiled_labels(
     per_slice: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Consecutive volume CC from tile-local minimal-flat-index labels
-    (−1 background): resolve the tile-face equivalences with the compact
-    value union-find, then rank.  Generalizes ``merge_slice_labels`` (tiles
-    = whole slices) to arbitrary tile grids; shared with the tiled Pallas
-    kernel (ops/pallas_cc.py)."""
+    (−1 background): resolve the tile-face equivalences with the dense
+    flat-index union-find (``merge_dense_values``), then rank.  Generalizes
+    ``merge_slice_labels`` (tiles = whole slices) to arbitrary tile grids;
+    shared with the tiled Pallas kernel (ops/pallas_cc.py)."""
     shape = mask.shape
     size = int(np.prod(shape))
     sent = jnp.int32(size)
@@ -784,11 +802,10 @@ def merge_tiled_labels(
         L, None, tile, connectivity, per_slice, size
     )
     if pairs is not None:
-        from .unionfind import apply_value_roots, merge_value_table
+        from .unionfind import merge_dense_values
 
         a_vals, b_vals, _ = pairs
-        vals, root_vals = merge_value_table(a_vals, b_vals)
-        L = apply_value_roots(L, vals, root_vals)
+        L = merge_dense_values(L, a_vals, b_vals, 0, size, sent)
     flat = jnp.where(mask.reshape(-1), L.reshape(-1), -1)
     labels, n = consecutive_from_flat_roots(flat, size)
     return labels.reshape(shape), n

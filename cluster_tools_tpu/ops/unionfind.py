@@ -122,11 +122,14 @@ def merge_value_table(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Union-find over the *values* appearing in equivalence pairs
     ``(a_vals[i], b_vals[i])`` — the compact form of ``merge_labels_device``
-    for sparse id spaces (ctt-cc tile-face merging, parallel/sharded.py
-    shard-face merging): the parent table covers only the values that occur
-    in the pairs (O(#pairs) entries), not the dense id range they are drawn
-    from, so resolving tile-boundary equivalences of a volume costs
-    O(boundary), not O(volume).
+    for id spaces the program cannot bound: the hierarchical flood's
+    segment tables (ops/hier.py) and the all-gathered shard faces of
+    parallel/sharded.py's stage 2.  The parent table covers only the values
+    that occur in the pairs (O(#pairs) entries), not the id range they are
+    drawn from.  Values that are flat indices of one block (the coarse CC's
+    tile faces, ``ops.cc.merge_tiled_labels``) take
+    :func:`merge_dense_values` instead, which indexes that range directly
+    rather than binary-searching a sorted table.
 
     Padding slots must carry the same value on both sides (self-loops merge
     nothing).  Returns ``(vals, root_vals)``: ``vals`` is the sorted multiset
@@ -158,6 +161,48 @@ def apply_value_roots(
     idx = jnp.clip(jnp.searchsorted(vals, x), 0, n - 1).astype(jnp.int32)
     hit = vals[idx] == x
     return jnp.where(hit, root_vals[idx], x)
+
+
+def merge_dense_values(
+    x: jnp.ndarray,
+    a_vals: jnp.ndarray,
+    b_vals: jnp.ndarray,
+    id_offset,
+    size: int,
+    sentinel,
+) -> jnp.ndarray:
+    """``apply_value_roots(x, *merge_value_table(a_vals, b_vals))`` for
+    values drawn from one dense range: every value of ``x``, ``a_vals`` and
+    ``b_vals`` lies in ``[id_offset, id_offset + size)`` or equals
+    ``sentinel``.  Each value has a slot (``value - id_offset``, the
+    sentinel ``size``), so the lookups are gathers from tables of
+    ``size + 1`` entries instead of binary searches.
+
+    The values that occur in the pairs are numbered by a prefix count over
+    their slots; those compact ids are order-isomorphic to the values, so
+    ``merge_labels_device``'s link-to-min resolves each class to its minimal
+    value, and the table stays O(#pairs) like ``merge_value_table``'s.
+    """
+    def slot(v):
+        return jnp.where(v == sentinel, size, v - id_offset).astype(jnp.int32)
+
+    m = a_vals.shape[0]
+    s = slot(jnp.concatenate([a_vals, b_vals]))
+    present = jnp.zeros((size + 1,), bool).at[s].set(True)
+    compact = jnp.cumsum(present, dtype=jnp.int32) - 1
+    c = compact[s]
+    n = min(s.shape[0], size + 1)
+    roots = merge_labels_device(
+        jnp.arange(n, dtype=jnp.int32), jnp.stack([c[:m], c[m:]], axis=1)
+    )
+    slot_of = jnp.zeros((n,), jnp.int32).at[c].set(s)
+    table = jnp.where(
+        present,
+        slot_of[roots][jnp.maximum(compact, 0)],
+        jnp.arange(size + 1, dtype=jnp.int32),
+    )
+    out = table[slot(x)]
+    return jnp.where(out == size, sentinel, out + id_offset).astype(x.dtype)
 
 
 @partial(jax.jit)

@@ -242,18 +242,18 @@ def _sharded_cc(mask, connectivity, axis_name, mesh):
     def local_fn(m):
         shard = lax.axis_index(axis_name)
         offset = shard * local_size
-        gids = (
-            jnp.arange(local_size, dtype=jnp.int32).reshape(local_shape)
-            + offset
-        )
         sentinel = jnp.int32(size)
 
         # -- stage 1: shard-local fixpoint, global-id labels ---------------
         if coarse:
             label, _ = _coarse_cc_core(
-                m, gids, size, connectivity, None, False, tile
+                m, offset, size, connectivity, None, False, tile
             )
         else:
+            gids = (
+                jnp.arange(local_size, dtype=jnp.int32).reshape(local_shape)
+                + offset
+            )
             init = jnp.where(m, gids, sentinel)
 
             def body(state):

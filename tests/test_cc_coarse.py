@@ -14,6 +14,8 @@ Three invariants, each BIT-exact (not just partition-equal):
     fixpoint with no more global rounds than the flat flood.
 """
 
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -125,6 +127,36 @@ class TestCoarseParity:
         mask = rng.random((4, 6, 5)) < 0.6
         _assert_all_paths_exact(mask, tiles=((1, 1, 1),))
 
+    @pytest.mark.parametrize("connectivity", [1, 2, 3])
+    @pytest.mark.parametrize("per_slice", [False, True])
+    def test_vmapped_batch_matches_flat(self, rng, connectivity, per_slice):
+        # the block programs vmap the CC over a device batch; a shape the
+        # tile does not divide, so edge tiles are padded
+        masks = jnp.asarray(rng.random((2, 9, 13, 11)) < 0.5)
+
+        def run(tile):
+            return jax.vmap(lambda m: C.connected_components(
+                m, connectivity, per_slice=per_slice, coarse_tile=tile))(masks)
+
+        with _backend.force_cc_mode("flat"):
+            want, n_want = run(None)
+        got, n_got = run((4, 8, 8))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(n_got), np.asarray(n_want))
+
+    def test_vmapped_partition_matches_flat(self, rng):
+        segs = jnp.asarray((rng.random((2, 10, 13, 9)) * 3).astype(np.int32))
+
+        def run(tile):
+            return jax.vmap(lambda s: C.connected_components_labels(
+                s, connectivity=2, coarse_tile=tile))(segs)
+
+        with _backend.force_cc_mode("flat"):
+            want, n_want = run(None)
+        got, n_got = run((4, 8, 8))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(n_got), np.asarray(n_want))
+
 
 class TestIterationContract:
     def test_serpentine_tile_bounded_rounds(self):
@@ -168,6 +200,44 @@ class TestValueTable:
         # {3,7} → 3, {12,41,100} → 12, self-loop 999 → itself, absent
         # values (1, 55) pass through untouched
         np.testing.assert_array_equal(out, [1, 3, 3, 12, 12, 12, 55, 999])
+
+    @pytest.mark.parametrize("id_offset", [0, 1000, 40000])
+    @pytest.mark.parametrize("seed", [0, 1])
+    def test_dense_merge_matches_value_table(self, id_offset, seed):
+        # the flat-index merge of the coarse CC gives what the sorted value
+        # table gives, for pairs with duplicates, chains, sentinel
+        # self-loops and ids offset as a shard's are
+        from cluster_tools_tpu.ops.unionfind import (
+            apply_value_roots,
+            merge_dense_values,
+            merge_value_table,
+        )
+
+        rng = np.random.default_rng(seed)
+        size = 300
+        sentinel = 50000
+        m = 400
+        a = rng.integers(0, size, m) + id_offset
+        b = rng.integers(0, size, m) + id_offset
+        b[:40] = a[:40]  # self-loops on real values
+        a[40:80] = b[40:80] = sentinel  # padding slots
+        a[80:120] = a[120:160]  # duplicated values
+        a, b = a.astype(np.int32), b.astype(np.int32)
+        x = np.concatenate(
+            [np.arange(size) + id_offset, [sentinel]]
+        ).astype(np.int32)
+        rng.shuffle(x)
+        want = apply_value_roots(x, *merge_value_table(a, b))
+        got = jax.jit(merge_dense_values, static_argnames="size")(
+            jnp.asarray(x), jnp.asarray(a), jnp.asarray(b),
+            jnp.int32(id_offset), size=size, sentinel=jnp.int32(sentinel),
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # something merged, and values in no pair kept their own id
+        assert (np.asarray(got) != x).any()
+        untouched = ~np.isin(x, np.concatenate([a, b]))
+        assert untouched.any()
+        np.testing.assert_array_equal(np.asarray(got)[untouched], x[untouched])
 
 
 class TestTileResolution:
@@ -261,6 +331,64 @@ class TestShardedCoarse:
         with _backend.force_cc_mode("coarse"):
             got = np.asarray(sharded_connected_components(mask))
         np.testing.assert_array_equal(got, ref)
+
+
+class TestMergeLowering:
+    """Which merge each lowered program holds: the coarse CC inside the two
+    block programs indexes the block's flat-index range, with no binary
+    search under its phase scopes; the sharded CC's stage 2, whose values
+    are all-gathered from every shard, keeps the sorted value table."""
+
+    SHAPE = (16, 96, 96)  # 2x2x2 default tiles, so the tile faces merge
+
+    @staticmethod
+    def _cc_paths(lowered):
+        from test_program_scopes import op_paths
+
+        paths = [p or "" for _, p in op_paths(
+            lowered.as_text(debug_info=True))]
+        cc = [p for p in paths if re.search(r"(^|/)cc\.(tiles|merge)/", p)]
+        assert any(re.search(r"(^|/)cc\.merge/", p) for p in cc)
+        return paths, cc
+
+    def test_components_program_has_no_search(self):
+        from cluster_tools_tpu.tasks.thresholded_components import (
+            _components_batch,
+        )
+
+        fn = jax.jit(lambda b, t: _components_batch(b, t, "greater", 0.0, 1))
+        with _backend.force_cc_mode("coarse"):
+            low = fn.lower(
+                jax.ShapeDtypeStruct((1,) + self.SHAPE, jnp.float32),
+                jax.ShapeDtypeStruct((), jnp.float32),
+            )
+        _, cc = self._cc_paths(low)
+        assert not [p for p in cc if "searchsorted" in p]
+
+    def test_seed_cc_has_no_search(self):
+        from cluster_tools_tpu.ops.watershed import dt_seeds
+
+        with _backend.force_cc_mode("coarse"):
+            low = dt_seeds.lower(
+                jax.ShapeDtypeStruct(self.SHAPE, jnp.float32), per_slice=True
+            )
+        _, cc = self._cc_paths(low)
+        assert not [p for p in cc if "searchsorted" in p]
+
+    def test_sharded_stage_two_keeps_the_sorted_table(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from cluster_tools_tpu.parallel.sharded import _sharded_cc
+
+        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+        mask = jax.ShapeDtypeStruct(
+            self.SHAPE, jnp.bool_, sharding=NamedSharding(mesh, P("data"))
+        )
+        with _backend.force_cc_mode("coarse"):
+            low = _sharded_cc.lower(mask, 1, "data", mesh)
+        paths, cc = self._cc_paths(low)
+        assert not [p for p in cc if "searchsorted" in p]
+        assert [p for p in paths if "searchsorted" in p]
 
 
 class TestPallasTiled:
