@@ -118,6 +118,11 @@ COUNTERS = frozenset({
     "faults.injected",
     # parallel/sharded.py — collective→local degradations
     "sharded.fallback_local",
+    # tasks/thresholded_components.py ShardedComponentsTask — what the
+    # collective CC program returns beside its labels (parallel/sharded.py)
+    "scc.shards",               # mesh shards of the programs that counted
+    "scc.local_rounds",         # stage-1 fixpoint rounds, summed over shards
+    "scc.face_pairs",           # live cross-shard face pairs in the table
     # runtime/queue.py — ctt-steal work-stealing scheduler
     "sched.leases_claimed",      # lease links won (gen 0 + requeues)
     "sched.leases_expired",      # leases found stale (3x cadence) on claim

@@ -144,12 +144,16 @@ def put_global(arr, mesh: Mesh, axis_name: str = "data", dtype=None):
 
 def put_from_store(ds, mesh: Mesh, axis_name: str = "data", dtype=None,
                    pad_to: Optional[int] = None, transform=None,
-                   pad_value=0):
+                   pad_value=0, box=None):
     """Stream a chunked-store dataset onto the mesh sharding shard-by-shard:
     the placement callback reads each shard's region directly from the
     store, so no full-volume host copy ever exists (the practical bound
     becomes one shard, not the volume — and on a multi-host mesh each
     process reads only its own slab from shared storage).
+
+    ``box``: ``(begin, end)`` of the region to place (an ROI); the array
+    then has the box's shape and each shard reads its z-range of the box
+    over the box's in-plane window.  None is the whole dataset.
 
     ``pad_to``: pad the leading axis up to a multiple of this, for meshes
     that do not divide the raw extent — the pad is ``pad_value`` in the
@@ -159,7 +163,10 @@ def put_from_store(ds, mesh: Mesh, axis_name: str = "data", dtype=None,
     it crosses to the device.  Narrowing transforms (e.g. thresholding a
     float volume to its bool mask) keep HBM at the narrow dtype — only the
     transformed shard ever leaves the host."""
-    shape = list(ds.shape)
+    if box is None:
+        box = ((0,) * len(ds.shape), tuple(ds.shape))
+    begin = tuple(int(b) for b in box[0])
+    shape = [int(e) - b for b, e in zip(begin, box[1])]
     z = shape[0]
     if pad_to:
         shape[0] = z + ((-z) % pad_to)
@@ -167,13 +174,20 @@ def put_from_store(ds, mesh: Mesh, axis_name: str = "data", dtype=None,
     sharding = NamedSharding(mesh, P(axis_name))
     out_dtype = np.dtype(dtype) if dtype is not None else ds.dtype
 
+    def region(sl, b, n):
+        return slice(b + (sl.start or 0), b + (n if sl.stop is None
+                                               else sl.stop))
+
     def read(idx):
         sl0 = idx[0]
         start, stop = sl0.start or 0, sl0.stop or shape[0]
         stop_real = min(stop, z)
         block = np.full((stop - start,) + shape[1:], pad_value, dtype=out_dtype)
         if start < z:
-            part = np.asarray(ds[(slice(start, stop_real),) + idx[1:]])
+            inner = tuple(region(sl, b, n) for sl, b, n in
+                          zip(idx[1:], begin[1:], shape[1:]))
+            part = np.asarray(ds[(slice(begin[0] + start,
+                                        begin[0] + stop_real),) + inner])
             if transform is not None:
                 part = transform(part)
             block[: stop_real - start] = part.astype(out_dtype, copy=False)

@@ -6,21 +6,28 @@ goes through the chunked store.  This module is the other half of the
 SURVEY.md §2.8/§2.9 mapping: when one volume is larger than a chip's HBM, the
 volume itself is sharded over the mesh (blocks = "sequence shards") and
 neighbor communication rides **ICI collectives inside one jit program** —
-`lax.ppermute` halo exchange along the sharded axis, `lax.psum` convergence
-votes — instead of filesystem round-trips.  This is the spatial analog of
-ring attention's neighbor exchange (SURVEY.md §5 "long-context").
+`lax.ppermute` boundary exchange along the sharded axis, `lax.all_gather`
+of the cross-shard tables, `lax.psum` convergence votes — instead of
+filesystem round-trips.  This is the spatial analog of ring attention's
+neighbor exchange (SURVEY.md §5 "long-context").
 
 Kernels:
 
   * ``halo_exchange`` — pad a z-sharded array with its neighbors' boundary
     planes (the reference's overlapping block reads, volume_utils
     getBlockWithHalo, as an ICI ring exchange).
-  * ``sharded_connected_components`` — global CC of a z-sharded volume:
-    per-shard log-depth min-label sweeps (ops.cc) + boundary-plane exchange,
-    iterated inside one ``lax.while_loop`` until the *global* fixpoint
-    (``psum`` of per-shard change flags).  The cross-shard merge that the
-    block pipeline does via face files + union-find (ThresholdedComponents
-    steps 3-4) happens entirely on the mesh.
+  * ``sharded_connected_components`` — global CC of a z-sharded volume,
+    with no collective rounds: each shard labels its slab to its local
+    fixpoint (ops.cc), one ``ppermute`` of the boundary planes and one
+    ``all_gather`` of the face pairs build the whole cross-shard
+    equivalence table, and a union-find over it, replicated on every
+    shard, relabels the shard (phases ``scc.local``, ``scc.exchange``,
+    ``scc.resolve``).  The cross-shard merge that the block pipeline does
+    via face files + union-find (ThresholdedComponents steps 3-4) happens
+    entirely on the mesh.
+  * ``sharded_seeded_watershed`` — the seeded flood of a z-sharded volume:
+    per-shard sweeps, ``ppermute``'d boundary relaxation and ``psum``
+    convergence votes inside ``lax.while_loop``s.
 
 Tested on the 8-virtual-device CPU mesh against the scipy oracle
 (tests/test_sharded.py); the same program runs unchanged on a real ICI mesh.
@@ -212,14 +219,24 @@ def _local_relax(label, mask, offsets, axes, size, shard_offset, local_size):
 @partial(jax.jit, static_argnames=("connectivity", "axis_name", "mesh"))
 def _sharded_cc(mask, connectivity, axis_name, mesh):
     """Coarse-to-fine CC at shard granularity (ctt-cc, the shard-level
-    instance of ops/cc.py's tile scheme): each shard labels its slab to its
-    LOCAL fixpoint in global-id space (no collectives — the rounds are
-    bounded by in-shard structure), then ONE plane exchange + all-gather
-    builds the complete cross-shard equivalence table, resolved by the
-    compact value union-find replicated on every shard and applied with one
-    gather.  Replaces the pre-ctt-cc global fixpoint loop, whose label
-    information crawled one shard per round (local relax + plane merge +
-    psum vote, O(n_shards · local rounds) collective rounds)."""
+    instance of ops/cc.py's tile scheme), in three phases, each under its
+    named scope:
+
+    * ``scc.local``: each shard labels its slab to its LOCAL fixpoint in
+      global-id space, with no collectives (the coarse kernel's tile
+      fixpoints and tile-face merge, ``cc.tiles``/``cc.merge`` nested, or
+      the flat relaxation loop);
+    * ``scc.exchange``: ONE ``ppermute`` of the shard-boundary planes and
+      one ``all_gather`` of the resulting face pairs, so every shard holds
+      the complete cross-shard equivalence table;
+    * ``scc.resolve``: the compact value union-find over that table,
+      replicated on every shard, applied to the shard's labels with one
+      search of every voxel (``merge_value_table``/``apply_value_roots``).
+
+    Returns ``(labels, rounds, face_pairs)``: int32 labels (background -1,
+    each component its minimal global flat index), and per shard (one
+    int32 each, sharded like the labels) the stage-1 round count and the
+    number of live face pairs the shard contributed to the table."""
     shape = mask.shape
     size = int(np.prod(shape))
     if size >= np.iinfo(np.int32).max:
@@ -240,61 +257,70 @@ def _sharded_cc(mask, connectivity, axis_name, mesh):
     tile = resolve_coarse_tile(local_shape, None) if coarse else None
 
     def local_fn(m):
-        shard = lax.axis_index(axis_name)
-        offset = shard * local_size
         sentinel = jnp.int32(size)
 
         # -- stage 1: shard-local fixpoint, global-id labels ---------------
-        if coarse:
-            label, _ = _coarse_cc_core(
-                m, offset, size, connectivity, None, False, tile
-            )
-        else:
-            gids = (
-                jnp.arange(local_size, dtype=jnp.int32).reshape(local_shape)
-                + offset
-            )
-            init = jnp.where(m, gids, sentinel)
-
-            def body(state):
-                lab, _ = state
-                new = _local_relax(
-                    lab, m, offsets, (0, 1, 2), size, offset, local_size
+        with jax.named_scope("scc.local"):
+            offset = lax.axis_index(axis_name) * local_size
+            if coarse:
+                label, stats = _coarse_cc_core(
+                    m, offset, size, connectivity, None, False, tile
                 )
-                return new, jnp.any(new != lab)
+                rounds = stats["fixpoint_iters"]
+            else:
+                gids = (
+                    jnp.arange(local_size, dtype=jnp.int32)
+                    .reshape(local_shape) + offset
+                )
+                init = jnp.where(m, gids, sentinel)
 
-            label, _ = lax.while_loop(
-                lambda s: s[1], body, (init, jnp.bool_(True))
-            )
+                def body(state):
+                    lab, _, n = state
+                    new = _local_relax(
+                        lab, m, offsets, (0, 1, 2), size, offset, local_size
+                    )
+                    return new, jnp.any(new != lab), n + 1
 
-        if n_shards == 1:
-            return jnp.where(m, label, jnp.int32(-1))
+                label, _, rounds = lax.while_loop(
+                    lambda s: s[1], body,
+                    (init, jnp.bool_(True), jnp.int32(0)),
+                )
 
-        # -- stage 2: one all-gathered boundary table ----------------------
-        # each shard contributes its +z face: own last plane against the +z
-        # neighbor's first plane (zero-filled mask past the mesh edge, so
-        # the last shard contributes only self-loop padding)
-        _, hi = _exchange_planes((label, m), axis_name)
-        hi_lab, hi_msk = hi
-        own_lab, own_msk = label[-1], m[-1]
-        a_parts, b_parts = [], []
-        for off in cross:
-            g_lab = _shift(hi_lab, off, sentinel)
-            g_msk = _shift(hi_msk, off, False)
-            ok = own_msk & g_msk & (g_lab < sentinel)
-            a_parts.append(jnp.where(ok, own_lab, sentinel).reshape(-1))
-            b_parts.append(jnp.where(ok, g_lab, sentinel).reshape(-1))
-        a = lax.all_gather(jnp.concatenate(a_parts), axis_name).reshape(-1)
-        b = lax.all_gather(jnp.concatenate(b_parts), axis_name).reshape(-1)
-        vals, root_vals = merge_value_table(a, b)
-        label = apply_value_roots(label, vals, root_vals)
-        return jnp.where(m, label, jnp.int32(-1))
+        pairs = jnp.int32(0)
+        if n_shards > 1:
+            # -- stage 2: one all-gathered boundary table ------------------
+            # each shard contributes its +z face: own last plane against the
+            # +z neighbor's first plane (zero-filled mask past the mesh
+            # edge, so the last shard contributes only self-loop padding)
+            with jax.named_scope("scc.exchange"):
+                _, hi = _exchange_planes((label, m), axis_name)
+                hi_lab, hi_msk = hi
+                own_lab, own_msk = label[-1], m[-1]
+                a_parts, b_parts = [], []
+                for off in cross:
+                    g_lab = _shift(hi_lab, off, sentinel)
+                    g_msk = _shift(hi_msk, off, False)
+                    ok = own_msk & g_msk & (g_lab < sentinel)
+                    pairs = pairs + jnp.sum(ok, dtype=jnp.int32)
+                    a_parts.append(
+                        jnp.where(ok, own_lab, sentinel).reshape(-1))
+                    b_parts.append(jnp.where(ok, g_lab, sentinel).reshape(-1))
+                a = lax.all_gather(
+                    jnp.concatenate(a_parts), axis_name).reshape(-1)
+                b = lax.all_gather(
+                    jnp.concatenate(b_parts), axis_name).reshape(-1)
+            with jax.named_scope("scc.resolve"):
+                vals, root_vals = merge_value_table(a, b)
+                label = apply_value_roots(label, vals, root_vals)
+        with jax.named_scope("scc.resolve"):
+            label = jnp.where(m, label, jnp.int32(-1))
+            return label, rounds.reshape(1), pairs.reshape(1)
 
     fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=P(axis_name),
-        out_specs=P(axis_name),
+        out_specs=(P(axis_name), P(axis_name), P(axis_name)),
         # the ops/cc.py sweeps seed their scan carries from shape constants,
         # which the varying-manual-axes check sees as replicated values
         # meeting per-shard ones; every value here is per-shard
@@ -486,7 +512,8 @@ def sharded_connected_components(
     mesh=None,
     axis_name: str = "data",
     connectivity: int = 1,
-) -> jnp.ndarray:
+    with_stats: bool = False,
+):
     """Global connected components of a volume z-sharded over the device mesh.
 
     Returns int32 labels where background = -1 and each component carries the
@@ -496,8 +523,16 @@ def sharded_connected_components(
     consecutive renumbering is identical).  The volume's z-extent must divide
     by the mesh size.
 
-    One jit program: per-shard sweeps + pointer jumping, ppermute'd boundary
-    planes, psum'd convergence — no host round-trips between rounds.
+    One jit program (``_sharded_cc``): each shard labels its slab to its
+    local fixpoint, one boundary-plane ``ppermute`` and one ``all_gather``
+    give every shard the cross-shard face pairs, and a replicated
+    union-find over them relabels each shard with one search of every
+    voxel — no host round-trips and no rounds of collectives.
+
+    ``with_stats`` returns ``(labels, stats)``: ``stats`` maps ``rounds``
+    and ``face_pairs`` to the program's per-shard int32 counts (stage-1
+    fixpoint rounds, live face pairs contributed), computed in every call
+    alike; None on the local fallback below.
 
     When collective setup fails (``CollectiveInitError`` — a wedged device
     runtime, or the ``collective.init`` fault site), the kernel degrades to
@@ -513,10 +548,11 @@ def sharded_connected_components(
         _note_local_fallback("sharded_connected_components", e)
         from ..ops.cc import connected_components_raw
 
-        return connected_components_raw(
+        labels = connected_components_raw(
             jnp.asarray(np.asarray(mask, dtype=bool)),
             connectivity=connectivity,
         )
+        return (labels, None) if with_stats else labels
     n = mesh.shape[axis_name]
     if mask.shape[0] % n:
         raise ValueError(
@@ -524,7 +560,10 @@ def sharded_connected_components(
         )
     mask = put_global(mask, mesh, axis_name, dtype=bool)
     faults.check("collective.execute")
-    return _sharded_cc(mask, connectivity, axis_name, mesh)
+    labels, rounds, pairs = _sharded_cc(mask, connectivity, axis_name, mesh)
+    if with_stats:
+        return labels, {"rounds": rounds, "face_pairs": pairs}
+    return labels
 
 
 def fused_threshold_components(
@@ -533,7 +572,8 @@ def fused_threshold_components(
     mesh=None,
     axis_name: str = "data",
     connectivity: int = 1,
-) -> jnp.ndarray:
+    with_stats: bool = False,
+):
     """ctt-stream under the sharded collective: threshold + global CC as
     one device-resident sequence — the boolean mask is born on device and
     flows straight into the collective label program, never crossing to
@@ -545,7 +585,7 @@ def fused_threshold_components(
     is supported: zero pad slabs then threshold to background, preserving
     the host-threshold path's pad contract — callers with other modes keep
     the host-side transform.  Labels match ``sharded_connected_components``
-    on the host-thresholded mask exactly.
+    on the host-thresholded mask exactly; ``with_stats`` as there.
     """
     if threshold < 0:
         raise ValueError(
@@ -554,5 +594,6 @@ def fused_threshold_components(
         )
     mask = jax.jit(lambda v: v > threshold)(x)
     return sharded_connected_components(
-        mask, mesh=mesh, axis_name=axis_name, connectivity=connectivity
+        mask, mesh=mesh, axis_name=axis_name, connectivity=connectivity,
+        with_stats=with_stats,
     )

@@ -397,7 +397,7 @@ def sharded_dt_watershed(
     # seed-plateau CC over the mesh (full connectivity, like dt_seeds)
     from .sharded import _sharded_cc
 
-    roots = _sharded_cc(maxima_d, 3, axis_name, mesh)
+    roots, _, _ = _sharded_cc(maxima_d, 3, axis_name, mesh)
     seeds_d = jnp.where(roots >= 0, roots + 1, 0).astype(jnp.int32)
 
     labels = sharded_seeded_watershed(

@@ -428,6 +428,20 @@ def _np_smooth(raw: np.ndarray, sigma) -> np.ndarray:
     return _ndi.gaussian_filter(raw.astype("float32"), sigma)
 
 
+def _smoothed_region(ds, sigma, begin, end) -> np.ndarray:
+    """``_np_smooth`` of the whole of ``ds``, cropped to ``[begin, end)``,
+    from a read of that box grown by the filter's radius (clipped to the
+    volume): inside the volume the halo covers the whole kernel, and at its
+    border the filter reflects where it would over the whole volume."""
+    sig = np.broadcast_to(np.asarray(sigma, dtype=float), (len(begin),))
+    # scipy's gaussian_filter radius at its default truncate of 4.0
+    radius = [int(4.0 * s + 0.5) for s in sig]
+    lo = [max(b - r, 0) for b, r in zip(begin, radius)]
+    hi = [min(e + r, n) for e, r, n in zip(end, radius, ds.shape)]
+    raw = _np_smooth(ds[tuple(slice(a, b) for a, b in zip(lo, hi))], sigma)
+    return raw[tuple(slice(b - a, e - a) for a, b, e in zip(lo, begin, end))]
+
+
 def _threshold_host(raw: np.ndarray, threshold: float, mode: str) -> np.ndarray:
     if mode == "greater":
         return raw > threshold
@@ -437,26 +451,36 @@ def _threshold_host(raw: np.ndarray, threshold: float, mode: str) -> np.ndarray:
 
 
 class ShardedComponentsTask(VolumeSimpleTask):
-    """Whole-volume connected components over the device mesh in ONE jit
-    program — the collective alternative to the 5-step block pipeline above.
+    """Connected components of the job's ROI (the global config's
+    ``roi_begin``/``roi_end``; the whole volume without one) over the
+    device mesh in ONE jit program — the collective alternative to the
+    5-step block pipeline above.
 
-    At ``sigma == 0`` (the default) the input streams from the store shard-
-    by-shard and each shard thresholds on host inside the placement
-    callback (``mesh.put_from_store(transform=...)``) — peak host RAM on
-    the ingest side is one shard and only the 1-byte/voxel bool mask ever
-    reaches HBM; with smoothing the full volume is gaussian-filtered on
-    host (scipy) first and the boolean mask crosses whole.  Labeling is
-    ``parallel.sharded.sharded_connected_components`` (per-shard sweeps +
-    ppermute'd boundary planes + psum convergence): the cross-block merge
-    that steps 2-4 route through the filesystem happens entirely over ICI.
+    The ROI is z-sharded over the mesh (its z extent padded with background
+    to a multiple of the mesh size).  At ``sigma == 0`` (the default) it
+    streams from the store shard-by-shard and each shard thresholds on host
+    inside the placement callback (``mesh.put_from_store(box=roi,
+    transform=...)``) — peak host RAM on the ingest side is one shard and
+    only the 1-byte/voxel bool mask reaches HBM; with ``device_threshold``
+    the float ROI crosses and thresholds on device; with smoothing the ROI
+    plus the filter's halo is gaussian-filtered on host (scipy) first, which
+    equals smoothing the whole volume, and the boolean mask crosses whole.
+    Labeling is ``parallel.sharded.sharded_connected_components``: local
+    fixpoints per shard, then one exchange of the shard faces over ICI — the
+    cross-block merge that steps 2-4 route through the filesystem.  Flat ids
+    are local to the ROI, so the int32 bound is the ROI's, not the volume's.
     Bounds: the labels round-trip through host for the consecutive relabel
-    (int32/voxel), and the mask must fit the mesh's aggregate HBM; the
-    block pipeline remains the truly out-of-core path.  Output is consecutive
-    uint64 labels (background 0) matching the block pipeline's partition at
-    ``sigma == 0``; with smoothing the two differ at block borders by design
-    — the block path smooths each halo-less block (truncating the filter at
-    every block boundary, as the reference's block_components does), while
-    this path smooths the whole volume seamlessly.
+    (int32/voxel), and the mask must fit the mesh's aggregate HBM; the block
+    pipeline remains the truly out-of-core path.  Output is a dataset of the
+    volume's shape in which only the ROI's chunks are written: consecutive
+    uint64 labels within the job (background 0) matching the block
+    pipeline's partition over a block-aligned ROI at ``sigma == 0``; with
+    smoothing the two differ at block borders by design — the block path
+    smooths each halo-less block (truncating the filter at every block
+    boundary, as the reference's block_components does), while this path
+    smooths seamlessly.  Host spans ``scc:ingest``, ``scc:program``
+    (dispatch to fetched labels), ``scc:relabel`` and ``scc:write``; obs
+    counters ``scc.shards``, ``scc.local_rounds`` and ``scc.face_pairs``.
     """
 
     task_name = "sharded_components"
@@ -487,16 +511,39 @@ class ShardedComponentsTask(VolumeSimpleTask):
         )
         return conf
 
+    @staticmethod
+    def _roi(conf, shape):
+        """``(begin, end)`` of the global config's ROI clipped to
+        ``shape``, or of the whole volume."""
+        begin, end = conf.get("roi_begin"), conf.get("roi_end")
+        if begin is None and end is None:
+            return (0,) * len(shape), tuple(int(s) for s in shape)
+        if begin is None or end is None:
+            raise ValueError(
+                "either both or none of roi_begin / roi_end must be given")
+        begin = tuple(max(int(b), 0) for b in begin)
+        end = tuple(int(s) if e is None else min(int(e), int(s))
+                    for e, s in zip(end, shape))
+        if any(e <= b for b, e in zip(begin, end)):
+            raise ValueError(f"empty ROI {begin}..{end} in volume {shape}")
+        return begin, end
+
     def run_impl(self) -> None:
         import jax
 
+        from ..obs import metrics as obs_metrics
+        from ..obs import trace as obs_trace
         from ..parallel.mesh import (
+            fetch_global,
             get_mesh,
             put_from_store,
             put_global,
             resolve_devices,
         )
-        from ..parallel.sharded import sharded_connected_components
+        from ..parallel.sharded import (
+            fused_threshold_components,
+            sharded_connected_components,
+        )
         from ..utils import store as store_mod
 
         conf = {**self.global_config(), **self.get_task_config()}
@@ -505,12 +552,15 @@ class ShardedComponentsTask(VolumeSimpleTask):
             raise ValueError(f"unsupported threshold_mode {mode!r}")
         in_ds = store_mod.file_reader(self.input_path, "r")[self.input_key]
         store_mod.set_read_threads(in_ds, read_threads(conf))
-        z = int(in_ds.shape[0])
+        box = self._roi(conf, in_ds.shape)
+        roi = tuple(slice(b, e) for b, e in zip(*box))
+        z = box[1][0] - box[0][0]
         devices = resolve_devices(conf)
         mesh = get_mesh(devices)
         n_dev = len(devices)
         threshold = float(conf.get("threshold", 0.5))
         sigma = conf.get("sigma", 0.0) or 0.0  # scalar or per-axis sequence
+        connectivity = int(conf.get("connectivity", 1))
 
         device_threshold = (
             bool(conf.get("device_threshold", False))
@@ -519,71 +569,79 @@ class ShardedComponentsTask(VolumeSimpleTask):
             and not self.mask_path
             and not np.any(np.asarray(sigma) > 0)
         )
-        if device_threshold:
-            # ctt-stream collective fusion: the raw volume streams to HBM
-            # and thresholds there, feeding the CC program directly — the
-            # mask intermediate never exists host-side
-            from ..parallel.mesh import fetch_global
-            from ..parallel.sharded import fused_threshold_components
-
-            x_d = put_from_store(
-                in_ds, mesh, dtype=np.float32, pad_to=n_dev,
-            )
-            raw_labels = fetch_global(
-                fused_threshold_components(
-                    x_d, threshold, mesh=mesh,
-                    connectivity=int(conf.get("connectivity", 1)),
+        with obs_trace.span("scc:ingest", kind="host_io"):
+            if device_threshold:
+                # ctt-stream collective fusion: the raw ROI streams to HBM
+                # and thresholds there, feeding the CC program directly —
+                # the mask intermediate never exists host-side
+                x_d = put_from_store(
+                    in_ds, mesh, dtype=np.float32, pad_to=n_dev, box=box,
                 )
-            )[:z]
-            self._write_labels(raw_labels, conf, n_dev)
-            return
+            elif np.any(np.asarray(sigma) > 0):
+                # smoothing runs on host (scipy) over the ROI and its halo
+                # — the full-copy path; sigma == 0 streams instead (below)
+                raw = _smoothed_region(in_ds, sigma, *box)
+                mask = _threshold_host(raw, threshold, mode)
+                del raw
+                if self.mask_path:
+                    m = store_mod.file_reader(
+                        self.mask_path, "r")[self.mask_key]
+                    mask &= m[roi].astype(bool)
+                pad = (-z) % n_dev
+                if pad:
+                    mask = np.pad(
+                        mask, ((0, pad),) + ((0, 0),) * (mask.ndim - 1))
+                mask_d = put_global(mask, mesh, dtype=bool)
+                del mask
+            else:
+                # stream shard-by-shard from the store, thresholding each
+                # shard on host inside the read callback: peak host RAM is
+                # one shard and only the 1-byte/voxel bool mask ever crosses
+                # to HBM (ADVICE r2; the zero pad slab is bool False by
+                # construction, so no pad-foreground guard is needed for
+                # any mode)
+                mask_d = put_from_store(
+                    in_ds, mesh, dtype=bool, pad_to=n_dev, box=box,
+                    transform=lambda part: _threshold_host(
+                        part.astype("float32"), threshold, mode
+                    ),
+                )
+                if self.mask_path:
+                    m_ds = store_mod.file_reader(
+                        self.mask_path, "r")[self.mask_key]
+                    m_d = put_from_store(
+                        m_ds, mesh, dtype=bool, pad_to=n_dev, box=box)
+                    mask_d = jax.jit(jax.numpy.logical_and)(mask_d, m_d)
 
-        if np.any(np.asarray(sigma) > 0):
-            # smoothing runs on host over the full volume (scipy) — the
-            # full-copy path; sigma == 0 streams instead (below)
-            raw = _np_smooth(in_ds[:], sigma)
-            mask = _threshold_host(raw, threshold, mode)
-            del raw
-            if self.mask_path:
-                m = store_mod.file_reader(self.mask_path, "r")[self.mask_key]
-                mask &= m[:].astype(bool)
-            pad = (-z) % n_dev
-            if pad:
-                mask = np.pad(mask, ((0, pad),) + ((0, 0),) * (mask.ndim - 1))
-            mask_d = put_global(mask, mesh, dtype=bool)
-            del mask
-        else:
-            # stream shard-by-shard from the store, thresholding each shard
-            # on host inside the read callback: peak host RAM is one shard
-            # and only the 1-byte/voxel bool mask ever crosses to HBM
-            # (ADVICE r2; the zero pad slab is bool False by construction,
-            # so no pad-foreground guard is needed for any mode)
-            mask_d = put_from_store(
-                in_ds, mesh, dtype=bool, pad_to=n_dev,
-                transform=lambda part: _threshold_host(
-                    part.astype("float32"), threshold, mode
-                ),
-            )
-            if self.mask_path:
-                m_ds = store_mod.file_reader(self.mask_path, "r")[self.mask_key]
-                m_d = put_from_store(m_ds, mesh, dtype=bool, pad_to=n_dev)
-                mask_d = jax.jit(jax.numpy.logical_and)(mask_d, m_d)
+        with obs_trace.span("scc:program", kind="collective"):
+            if device_threshold:
+                labels_d, stats = fused_threshold_components(
+                    x_d, threshold, mesh=mesh, connectivity=connectivity,
+                    with_stats=True,
+                )
+            else:
+                labels_d, stats = sharded_connected_components(
+                    mask_d, mesh=mesh, connectivity=connectivity,
+                    with_stats=True,
+                )
+            raw_labels = fetch_global(labels_d)[:z]
+        if stats is not None:  # the local fallback counts nothing
+            # what the program returned beside its labels: the benchmark
+            # divides the stage-1 rounds by the shards
+            obs_metrics.inc("scc.shards", n_dev)
+            obs_metrics.inc("scc.local_rounds",
+                            int(fetch_global(stats["rounds"]).sum()))
+            obs_metrics.inc("scc.face_pairs",
+                            int(fetch_global(stats["face_pairs"]).sum()))
+        self._write_labels(raw_labels, conf, n_dev, in_ds.shape, roi)
 
-        from ..parallel.mesh import fetch_global
-
-        raw_labels = fetch_global(
-            sharded_connected_components(
-                mask_d, mesh=mesh,
-                connectivity=int(conf.get("connectivity", 1)),
-            )
-        )[:z]
-        self._write_labels(raw_labels, conf, n_dev)
-
-    def _write_labels(self, raw_labels, conf, n_dev: int) -> None:
-        """Relabel + write the collective CC result (shared by the
+    def _write_labels(self, raw_labels, conf, n_dev: int, shape, roi) -> None:
+        """Relabel the collective CC result of the ROI consecutively and
+        write it into the ROI of the volume-shaped output (shared by the
         host-threshold and device-threshold/fused ingest paths)."""
         import jax
 
+        from ..obs import trace as obs_trace
         from ..utils import store as store_mod
 
         if jax.process_index() != 0:
@@ -594,14 +652,18 @@ class ShardedComponentsTask(VolumeSimpleTask):
         # shared helper keeps zero
         from ..ops.relabel import relabel_consecutive_np
 
-        shifted = np.where(raw_labels < 0, 0, raw_labels.astype(np.int64) + 1)
-        out, n_labels = relabel_consecutive_np(shifted.astype(np.uint64))
+        with obs_trace.span("scc:relabel"):
+            shifted = np.where(
+                raw_labels < 0, 0, raw_labels.astype(np.int64) + 1)
+            out, n_labels = relabel_consecutive_np(shifted.astype(np.uint64))
 
-        ds = self.require_output(out.shape, conf)
-        # threaded chunk-aligned whole-volume write (store fast path)
-        store_mod.set_read_threads(ds, read_threads(conf))
-        ds[:] = out
-        ds.attrs["n_labels"] = int(n_labels)
+        with obs_trace.span("scc:write", kind="host_io"):
+            ds = self.require_output(shape, conf)
+            # threaded write of the ROI's chunks (store fast path where a
+            # chunk lies inside the ROI)
+            store_mod.set_read_threads(ds, read_threads(conf))
+            ds[roi] = out
+            ds.attrs["n_labels"] = int(n_labels)
         self.log(
             f"sharded CC over {n_dev} devices: {n_labels} components"
         )

@@ -433,3 +433,91 @@ def test_monolithic_batch_spans_and_block_names(tmp_env, traced):
         s = next(s for s in spans if s["name"] == name)
         assert by_id[s["parent"]]["name"] == "block_batch"
     assert kinds["block:plain_blocks"] == "host"
+
+
+# -- the collective CC program (parallel/sharded.py) -------------------------
+
+SCC_PHASES = ("scc.local", "scc.exchange", "scc.resolve")
+SCC_SHAPE = (16, 96, 96)  # 2x2 default tiles per 4-plane shard
+
+
+def _lower_scc():
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cluster_tools_tpu.parallel.sharded import _sharded_cc
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    mask = jax.ShapeDtypeStruct(SCC_SHAPE, jnp.bool_,
+                                sharding=NamedSharding(mesh, P("data")))
+    return _sharded_cc.lower(mask, 1, "data", mesh)
+
+
+@pytest.mark.parametrize("cc_mode", ["flat", "coarse"])
+def test_collective_program_ops_hold_one_phase_scope(cc_mode):
+    """Every operation of the collective CC program lies in exactly one of
+    ``scc.local``, ``scc.exchange``, ``scc.resolve`` (the shard map's own
+    ``sdy.return`` and closing line are structure, not operations); the
+    coarse kernel's ``cc.*`` scopes nest inside ``scc.local``."""
+    with _backend.force_cc_mode(cc_mode):
+        text = _lower_scc().as_text(debug_info=True)
+    paths = op_paths(text)
+    assert len(paths) > 100
+    seen, bad = set(), []
+    for s, path in paths:
+        got = phases_in(path or "", "scc.")
+        seen |= got
+        structure = s.startswith(("sdy.return", "}"))
+        if len(got) != 1 and not structure and not (
+                "stablehlo.constant" in s and not got):
+            bad.append((path, s[:80]))
+        if phases_in(path or "", "cc."):
+            assert got == {"scc.local"}, path
+    assert not bad, bad[:10]
+    assert seen == set(SCC_PHASES)
+    nested = {p for _, p in paths if p and phases_in(p, "cc.")}
+    assert bool(nested) == (cc_mode == "coarse")
+    exchange = "\n".join(s for s, p in paths
+                         if phases_in(p or "", "scc.") == {"scc.exchange"})
+    assert "collective_permute" in exchange and "all_gather" in exchange
+
+
+def test_collective_tracing_changes_nothing_compiled(traced):
+    def fresh():
+        jax.clear_caches()
+        return _lower_scc().as_text()
+
+    on = fresh()
+    trace.disable()
+    assert fresh() == on
+
+
+def test_collective_task_counts_and_spans(tmp_path, traced):
+    """A run of the collective task adds its shards, stage-1 rounds and
+    live face pairs to the counters and writes its four host spans."""
+    from cluster_tools_tpu.obs.export import load_run
+    from cluster_tools_tpu.runtime import build
+    from cluster_tools_tpu.runtime import config as cfg
+    from cluster_tools_tpu.tasks.thresholded_components import (
+        ShardedComponentsTask,
+    )
+    from cluster_tools_tpu.utils import file_reader
+
+    x = np.zeros((16, 16, 16), np.float32)
+    x[:, 4, 4] = 1.0  # one column through every shard: 3 face pairs
+    x[2, 10:14, 10] = 1.0
+    path = str(tmp_path / "v.n5")
+    file_reader(path).create_dataset("raw", data=x, chunks=(8, 16, 16))
+    config_dir = str(tmp_path / "configs")
+    cfg.write_global_config(config_dir, {
+        "block_shape": [8, 16, 16], "devices": [0, 1, 2, 3]})
+    task = ShardedComponentsTask(
+        str(tmp_path / "tmp"), config_dir, input_path=path, input_key="raw",
+        output_path=path, output_key="cc")
+    assert build([task])
+    got = metrics.snapshot()["counters"]
+    assert got["scc.shards"] == 4
+    assert got["scc.local_rounds"] >= 4
+    assert got["scc.face_pairs"] == 3
+    trace.flush()
+    names = {s["name"] for s in load_run(traced)["spans"]}
+    assert {"scc:ingest", "scc:program", "scc:relabel", "scc:write"} <= names

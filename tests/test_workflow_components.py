@@ -164,3 +164,98 @@ def test_sharded_components_streaming_mask_and_nondivisible_z(tmp_path, rng):
     want, n_want = ndimage.label((raw < 0.5) & m)
     _assert_same_partition(got, want)
     assert int(file_reader(path, "r")["cc"].attrs["n_labels"]) == n_want
+
+
+# -- the collective path under a global-config ROI --------------------------
+
+ROI_CASES = {
+    # (volume shape, roi begin, roi end); blocks of 8x16x16 throughout
+    "aligned": ((32, 48, 48), (8, 16, 0), (24, 48, 32)),
+    # z extent 10: the 8- and the 4-device mesh pad it with background
+    "z_pad": ((34, 48, 48), (24, 0, 16), (34, 32, 48)),
+    # a U whose arms lie in the ROI and whose base lies outside it
+    "cut": ((32, 48, 48), (0, 0, 0), (16, 32, 32)),
+}
+SMOOTH_SIGMA = 1.5
+
+
+def _roi_volume(tmp_path, rng, case):
+    shape, begin, end = ROI_CASES[case]
+    raw = ndimage.gaussian_filter(rng.random(shape), 1.0)
+    raw = (0.5 * (raw - raw.min()) / (raw.max() - raw.min())).astype(
+        "float32")
+    raw[raw > 0.3] += 0.45  # components up to [0.75, 0.95]; rest <= 0.3
+    if case == "cut":
+        raw = np.minimum(raw, np.float32(0.3))
+        raw[2:20, 20, 20] = 0.9  # two arms, in the ROI from z 2 to 15
+        raw[2:20, 20, 26] = 0.9
+        raw[19, 20, 20:27] = 0.9  # joined at z 19, outside the ROI
+    path = str(tmp_path / "roi.n5")
+    file_reader(path).create_dataset("raw", data=raw, chunks=(8, 16, 16))
+    return path, raw, begin, end
+
+
+def _run_components(tmp_path, path, name, begin, end, sharded, task_conf,
+                    devices=None):
+    config_dir = str(tmp_path / f"configs_{name}")
+    gconf = {"block_shape": [8, 16, 16], "target": "tpu",
+             "roi_begin": list(begin), "roi_end": list(end)}
+    if devices:
+        gconf["devices"] = devices
+    cfg.write_global_config(config_dir, gconf)
+    cfg.write_config(config_dir, "block_components", {"threshold": 0.5})
+    cfg.write_config(config_dir, "sharded_components", task_conf)
+    wf = ThresholdedComponentsWorkflow(
+        str(tmp_path / f"tmp_{name}"), config_dir,
+        input_path=path, input_key="raw",
+        output_path=path, output_key=name, sharded=sharded,
+    )
+    assert build([wf])
+    return file_reader(path, "r")[name]
+
+
+@pytest.mark.parametrize("n_devices", [8, 4])
+@pytest.mark.parametrize("case,ingest", [
+    ("aligned", "host"), ("aligned", "device"), ("z_pad", "host"),
+    ("z_pad", "device"), ("cut", "host"), ("cut", "device"),
+    ("aligned", "smooth"),
+])
+def test_sharded_components_label_exactly_the_roi(tmp_path, rng, case,
+                                                  ingest, n_devices):
+    """Under a global-config ROI the collective task labels that ROI alone:
+    the partition of ``scipy.ndimage.label(raw[roi] > 0.5)`` (of the whole
+    volume smoothed, then cut to the ROI, with ``sigma``), and that of the
+    block path over the same ROI; consecutive ids within the job, the
+    volume's shape, and no chunk written outside the ROI."""
+    from cluster_tools_tpu.tasks.thresholded_components import _np_smooth
+
+    path, raw, begin, end = _roi_volume(tmp_path, rng, case)
+    roi = tuple(slice(b, e) for b, e in zip(begin, end))
+    conf = {"threshold": 0.5, "device_threshold": ingest == "device",
+            "sigma": SMOOTH_SIGMA if ingest == "smooth" else 0.0}
+    ds = _run_components(tmp_path, path, "sharded", begin, end, True, conf,
+                         devices=list(range(n_devices)))
+    got = ds[:]
+    assert ds.shape == raw.shape
+
+    src = _np_smooth(raw, SMOOTH_SIGMA) if ingest == "smooth" else raw
+    want, n_want = ndimage.label(src[roi] > 0.5)
+    assert n_want > (1 if case == "cut" else 5)
+    _assert_same_partition(got[roi], want)
+    ids = np.unique(got[roi])
+    assert ids[0] == 0 and (np.diff(ids) == 1).all() and ids[-1] == n_want
+    assert int(ds.attrs["n_labels"]) == n_want
+    if case == "cut":  # the arms meet only outside the ROI
+        assert got[5, 20, 20] != got[5, 20, 26]
+
+    inside = {tuple(p) for p in np.ndindex(*[
+        -(-e // c) for e, c in zip(end, ds.chunks)]) if all(
+        q >= b // c for q, b, c in zip(p, begin, ds.chunks))}
+    for pos in np.ndindex(*[-(-s // c) for s, c in zip(ds.shape,
+                                                       ds.chunks)]):
+        assert (ds.read_chunk(pos) is not None) == (pos in inside), pos
+
+    if ingest != "smooth":  # the block path smooths block by block
+        blocks = _run_components(tmp_path, path, "blocks", begin, end,
+                                 False, conf)
+        _assert_same_partition(got[roi], blocks[roi])
