@@ -154,3 +154,60 @@ def test_traced_rehearsal_of_the_collective_traffic(rehearsal, capsys,
     assert got["store.read_amp"] == pytest.approx(1.0, rel=1e-3)  # headers
     assert not [k for k in got if k.endswith("ms_per_mvox")]
     assert "roi ((0, 0, 0), (24, 48, 48))" in out.err  # one ROI, z whole
+
+
+def test_guard_refuses_a_program_that_labels_the_whole_volume(
+        rehearsal, capsys, monkeypatch, tmp_path):
+    """The cell on a collective task that ignores the ROI (labels the
+    whole volume under one, as the task did before it honoured the ROI):
+    the probe finds labels outside its ROI, the warm-up job fails and the
+    run exits 1 without a result, before any job of the window."""
+    import jax
+
+    from benchmark.harness import roi_workflow
+    from cluster_tools_tpu.tasks.thresholded_components import \
+        ShardedComponentsTask
+    from conftest import DATA
+
+    jax.clear_caches()
+    monkeypatch.setattr(roi_workflow, "_VERDICT", None)
+    monkeypatch.setattr(ShardedComponentsTask, "_roi", staticmethod(
+        lambda conf, shape: ((0,) * len(shape), tuple(shape))))
+    with open(os.path.join(DATA, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["workloads"] = [{"name": "tiny.cc_sharded", "config": "tiny",
+                           "traffic": "cc_sharded", "chips": 1,
+                           "why": "test"}]
+    bench_file = tmp_path / "BENCHMARK.json"
+    bench_file.write_text(json.dumps(bench))
+    monkeypatch.setattr(rehearsal, "BENCH_FILE", str(bench_file))
+    assert rehearsal.main(["--workload", "tiny.cc_sharded", "--seed",
+                           "2147483901", "--seconds", "0.05",
+                           "--trace", "0"]) == 1
+    out = capsys.readouterr()
+    assert out.out.strip() == ""
+    assert "labels the whole volume, not the job's ROI" in out.err
+    assert "cannot run: the warm-up job failed" in out.err
+    assert "window:" not in out.err
+
+
+def test_guard_passes_the_program_and_probes_once(monkeypatch, tmp_path):
+    """The program's collective task passes the probe; the verdict is
+    kept for the process, so later jobs build without probing again."""
+    from benchmark.harness import roi_workflow
+    from cluster_tools_tpu.runtime import config as cfg
+
+    calls = []
+    real = roi_workflow.probe_roi
+    monkeypatch.setattr(roi_workflow, "_VERDICT", None)
+    monkeypatch.setattr(roi_workflow, "probe_roi",
+                        lambda *a: calls.append(a) or real(*a))
+    config_dir = str(tmp_path / "configs")
+    cfg.write_global_config(config_dir, {"target": "tpu"})
+    for job in range(2):
+        wf = roi_workflow.ThresholdedComponentsWorkflow(
+            str(tmp_path / f"job{job}" / "tmp"), config_dir,
+            input_path="x.n5", input_key="raw", output_path="x.n5",
+            output_key="cc", sharded=True)
+        assert len(wf.requires()) == 1
+    assert len(calls) == 1 and roi_workflow._VERDICT == ""

@@ -26,9 +26,11 @@ class ThresholdedComponentsWorkflow(WorkflowBase):
     """threshold → block CC → offsets → faces → union-find → write.
 
     ``sharded=True`` replaces the 5-task block pipeline with ONE collective
-    task (``ShardedComponentsTask``): the volume z-shards over the device
-    mesh and the cross-block merge rides ICI (ppermute + psum) instead of
-    the scratch store — for volumes that fit the mesh's aggregate HBM.
+    task (``ShardedComponentsTask``): the global config's ROI (the whole
+    volume without one) z-shards over the device mesh and the cross-block
+    merge rides ICI (one ppermute of the shard faces, one all_gather of the
+    face pairs) instead of the scratch store — for ROIs that fit the mesh's
+    aggregate HBM.
     """
 
     task_name = "thresholded_components_workflow"
